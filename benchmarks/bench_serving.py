@@ -1,35 +1,32 @@
 """Serving benchmark: QPS / p50 / p99 / comparisons per engine x shard count.
 
 Drives ``launch/serve.SearchServer`` (the registry-driven front end) over a
-synthetic corpus for every engine at 1 and 2 corpus shards.  Multi-shard
-runs need >1 device, so the measurement runs in a child process with forced
-host-platform devices (the same isolation the dry-run and the dist tests
-use — the parent keeps its single device).  ``benchmarks/run.py`` writes the
-rows to ``experiments/BENCH_serving.json``, the serving-side perf
-trajectory regressed against by future PRs.
+synthetic corpus for every engine at 1 and ``shards`` corpus shards, in this
+process, on the devices ``jax.devices()`` reports.  A shard count above the
+device count raises: a CPU rehearsal of the sharded half gives itself host
+devices (``XLA_FLAGS=--xla_force_host_platform_device_count=2``).
+``benchmarks/run.py`` writes the rows to ``experiments/BENCH_serving.json``.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
 import sys
 
 if __name__ == "__main__":  # standalone: python benchmarks/bench_serving.py
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
-MARK = "BENCH_SERVING_JSON:"
 
+def run(n=2048, batch=64, batches=8, k=10, engines="brute,ivf_flat,nsw,infinity",
+        shards=2, budget=256, rerank=64, train_steps=200, proj_sample=512,
+        verbose=True):
+    """Measure every (engine, shard count) cell; returns the result rows.
 
-def _child_main(args) -> None:
-    """Runs with forced host devices; prints one JSON line of result rows.
-
-    Telemetry is enabled for the measured sweep (DESIGN.md §16): each row
-    carries a ``stages`` breakdown (per-stage comparisons + ms for the
-    cell) so the serving artifact shows where each engine's latency went."""
+    Telemetry is on for the sweep (DESIGN.md §16): each row carries a
+    ``stages`` breakdown (per-stage comparisons + ms for the cell) so the
+    serving artifact shows where each engine's latency went."""
+    import jax
     import numpy as np
 
     from benchmarks.common import recall_at_k, stage_breakdown
@@ -38,9 +35,11 @@ def _child_main(args) -> None:
     from repro.data import synthetic
     from repro.launch.serve import SearchServer, default_cfg
 
-    telem.enable()
-
-    n, batch, batches, k = args.n, args.batch, args.batches, args.k
+    if shards > jax.device_count():
+        raise RuntimeError(
+            f"shards={shards} needs {shards} devices; JAX reports "
+            f"{jax.device_count()} ({jax.devices()[0].platform})"
+        )
     n_q = batch * batches
     X = synthetic.make("manifold", n + n_q, seed=0)
     corpus, queries = X[:n], X[n:]
@@ -48,70 +47,41 @@ def _child_main(args) -> None:
     gt = index_lib.build("brute", corpus, {}).search(queries, k=k)
     gt_idx = np.asarray(gt.idx)
 
+    was_enabled = telem.enabled()
+    telem.enable()
     rows = []
     server = None
-    for engine in args.engines.split(","):
-        cfg = default_cfg(engine, budget=args.budget, rerank=args.rerank,
-                          train_steps=args.train_steps, proj_sample=args.proj_sample)
-        for shards in sorted({1, args.shards}):
-            if shards > 1 and n % shards != 0:
-                # visible truncation: the artifact must not pretend the
-                # sharded half of the sweep ran
-                print(f"SKIP {engine} shards={shards}: n={n} not divisible",
-                      file=sys.stderr)
-                continue
-            if server is None:
-                server = SearchServer(corpus, engine=engine, shards=shards, cfg=cfg)
-            else:
-                server.swap(engine, shards=shards, cfg=cfg)
-            telem.reset()  # stage window = this (engine, shards) cell only
-            stats = server.serve(qbatches, k=k, budget=args.budget)
-            res = server.query(queries, k=k, budget=args.budget)
-            stats["recall@k"] = recall_at_k(np.asarray(res.idx), gt_idx, k)
-            stats["n"] = n
-            stats["stages"] = stage_breakdown(engine)
-            # compiled-program roofline for this cell's serving dispatch
-            # (DESIGN.md §17); degraded to an error note, never a crash
-            try:
-                profs = server.capture_roofline(batch=batch, k=k,
-                                                budget=args.budget)
-                stats["roofline"] = next(iter(profs.values()), None)
-            except Exception as e:  # pragma: no cover
-                stats["roofline"] = {"error": f"{type(e).__name__}: {e}"[:200]}
-            rows.append(stats)
-    print(MARK + json.dumps(rows))
-
-
-def run(n=2048, batch=64, batches=8, k=10, engines="brute,ivf_flat,nsw,infinity",
-        shards=2, budget=256, rerank=64, train_steps=200, proj_sample=512,
-        verbose=True):
-    """Spawn the measurement child with forced host devices; parse its rows."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={max(shards, 2)} "
-        + env.get("XLA_FLAGS", "")
-    )
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [
-        sys.executable, os.path.abspath(__file__), "--child",
-        "--n", str(n), "--batch", str(batch), "--batches", str(batches),
-        "--k", str(k), "--engines", engines, "--shards", str(shards),
-        "--budget", str(budget), "--rerank", str(rerank),
-        "--train-steps", str(train_steps), "--proj-sample", str(proj_sample),
-    ]
-    r = subprocess.run(cmd, capture_output=True, text=True, env=env, timeout=3600)
-    if r.returncode != 0:
-        raise RuntimeError(f"serving child failed:\nSTDOUT:{r.stdout}\nSTDERR:{r.stderr}")
-    rows = None
-    for line in r.stdout.splitlines():
-        if line.startswith(MARK):
-            rows = json.loads(line[len(MARK):])
-    if rows is None:
-        raise RuntimeError(f"no result line in child output:\n{r.stdout}")
-    for line in r.stderr.splitlines():
-        if line.startswith("SKIP"):  # surface child-side sweep truncation
-            print(f"  {line}")
+    try:
+        for engine in engines.split(","):
+            cfg = default_cfg(engine, budget=budget, rerank=rerank,
+                              train_steps=train_steps, proj_sample=proj_sample)
+            for s in sorted({1, shards}):
+                if s > 1 and n % s != 0:
+                    # visible truncation: the artifact must not pretend the
+                    # sharded half of the sweep ran
+                    print(f"  SKIP {engine} shards={s}: n={n} not divisible")
+                    continue
+                if server is None:
+                    server = SearchServer(corpus, engine=engine, shards=s, cfg=cfg)
+                else:
+                    server.swap(engine, shards=s, cfg=cfg)
+                telem.reset()  # stage window = this (engine, shards) cell only
+                stats = server.serve(qbatches, k=k, budget=budget)
+                res = server.query(queries, k=k, budget=budget)
+                stats["recall@k"] = recall_at_k(np.asarray(res.idx), gt_idx, k)
+                stats["n"] = n
+                stats["stages"] = stage_breakdown(engine)
+                # compiled-program roofline for this cell's serving dispatch
+                # (DESIGN.md §17); degraded to an error note, never a crash
+                try:
+                    profs = server.capture_roofline(batch=batch, k=k,
+                                                    budget=budget)
+                    stats["roofline"] = next(iter(profs.values()), None)
+                except Exception as e:  # pragma: no cover
+                    stats["roofline"] = {"error": f"{type(e).__name__}: {e}"[:200]}
+                rows.append(stats)
+    finally:
+        telem.enable(was_enabled)
     if verbose:
         for rec in rows:
             print(
@@ -121,6 +91,17 @@ def run(n=2048, batch=64, batches=8, k=10, engines="brute,ivf_flat,nsw,infinity"
                 f"recall@{rec['k']}={rec['recall@k']:.3f}"
             )
     return rows
+
+
+def machine_shards(want: int = 2) -> int:
+    """The shard count a sweep can run here: ``want``, cut to the device
+    count with a visible SKIP line for the sharded half left out."""
+    import jax
+
+    have = jax.device_count()
+    if have < want:
+        print(f"  SKIP serving shards={want}: JAX reports {have} device(s)")
+    return min(want, have)
 
 
 def write_artifact(rows, path="experiments/BENCH_serving.json") -> None:
@@ -133,7 +114,6 @@ def write_artifact(rows, path="experiments/BENCH_serving.json") -> None:
 
 def _parse(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--child", action="store_true")
     ap.add_argument("--n", type=int, default=2048)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--batches", type=int, default=8)
@@ -149,12 +129,9 @@ def _parse(argv=None):
 
 if __name__ == "__main__":
     _args = _parse()
-    if _args.child:
-        _child_main(_args)
-    else:
-        write_artifact(run(
-            n=_args.n, batch=_args.batch, batches=_args.batches, k=_args.k,
-            engines=_args.engines, shards=_args.shards, budget=_args.budget,
-            rerank=_args.rerank, train_steps=_args.train_steps,
-            proj_sample=_args.proj_sample,
-        ))
+    write_artifact(run(
+        n=_args.n, batch=_args.batch, batches=_args.batches, k=_args.k,
+        engines=_args.engines, shards=_args.shards, budget=_args.budget,
+        rerank=_args.rerank, train_steps=_args.train_steps,
+        proj_sample=_args.proj_sample,
+    ))

@@ -14,9 +14,10 @@ import numpy as np
 
 
 def env_stamp() -> dict:
-    """Provenance of a benchmark run: git commit, jax version, backend and
-    device count.  Two artifacts are only comparable when their stamps say
-    they ran on comparable stacks — without this the numbers are anonymous."""
+    """Provenance of a benchmark run: git commit, jax version, backend,
+    device kind and count.  Two artifacts are only comparable when their
+    stamps say they ran on comparable stacks — without this the numbers are
+    anonymous."""
     try:
         commit = subprocess.run(
             ["git", "rev-parse", "HEAD"],
@@ -29,6 +30,7 @@ def env_stamp() -> dict:
         "git_commit": commit,
         "jax_version": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S%z"),
     }

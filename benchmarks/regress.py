@@ -170,7 +170,8 @@ def run_fresh(quick: bool, only: str = "") -> dict:
     if not only or "serving" in only:
         print("== fresh: serving ==", flush=True)
         rows = bench_serving.run(
-            n=2048, batches=8, k=10, shards=2, budget=256, rerank=64,
+            n=2048, batches=8, k=10, shards=bench_serving.machine_shards(2),
+            budget=256, rerank=64,
             engines="brute,ivf_flat" if quick
             else "brute,ivf_flat,nsw,infinity",
             train_steps=150 if quick else 300)

@@ -80,6 +80,9 @@ def search_profiles(d: str = "experiments") -> list:
         except (OSError, json.JSONDecodeError):
             continue
         rows = doc.get("rows", []) if isinstance(doc, dict) else []
+        # a share of a TPU's peak exists only for a run on a TPU
+        on_tpu = isinstance(doc, dict) and \
+            doc.get("meta", {}).get("backend") == "tpu"
         for r in rows:
             blocks = r.get("roofline")
             if not blocks:
@@ -94,6 +97,9 @@ def search_profiles(d: str = "experiments") -> list:
             )
             for _, blk in items:
                 if isinstance(blk, dict) and "program" in blk:
+                    if not on_tpu:
+                        blk = {k: v for k, v in blk.items()
+                               if k != "pct_of_peak"}
                     out.append((fname, ident, blk))
     return out
 

@@ -25,6 +25,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = args.quick
 
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
     from benchmarks import (
         bench_ann_compare,
         bench_depth_bound,
@@ -64,9 +67,11 @@ def main() -> None:
             ns=(128, 256) if quick else (256, 512, 1024))),
         ("topk_kernel", lambda: bench_topk_kernel.run(
             ns=(4096, 16384) if quick else (4096, 65536, 524288))),
-        # engine x shard-count serving sweep (child process: needs >1 device)
+        # engine x shard-count serving sweep, in-process: 2 shards where the
+        # machine has 2 devices, else the 1-shard half and a SKIP line
         ("serving", lambda: bench_serving.run(
             n=1024 if quick else 2048, batches=4 if quick else 8,
+            shards=bench_serving.machine_shards(2),
             engines="brute,ivf_flat,nsw" if quick else "brute,ivf_flat,nsw,infinity",
             train_steps=150 if quick else 300)),
         # interleaved upsert/delete/query churn through the live subsystem

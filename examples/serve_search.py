@@ -74,8 +74,8 @@ AOT, its optimized HLO pushed through the loop-aware ``dist/roofline``
 accounting, and the per-program flops / HBM bytes / arithmetic intensity
 / predicted-vs-measured time printed and exported as
 ``roofline_*{program=search:<engine>}`` gauges — ``roofline_pct_of_peak``
-says how close that program runs to the modeled hardware ceiling (tiny
-on the CPU demo backend, by design honest).
+says how close that program runs to the chip's ceiling; it exists only
+on a TPU (a CPU time measures no chip).
 
 Together with ``--metrics-port`` this is the full observatory: scrape
 ``/metrics`` and you get latency (``search_seconds``), quality
@@ -307,12 +307,14 @@ def main() -> None:
             try:
                 profs = server.capture_roofline(k=args.k, budget=args.budget)
                 for name, blk in profs.items():
+                    pct = blk.get("pct_of_peak")
+                    pct = "not measured" if pct is None else f"{pct:.4%}"
                     print(f"    roofline: {name} flops={blk['flops']:.3g} "
                           f"hbm={blk['hbm_bytes']:.3g}B "
                           f"AI={blk['intensity']:.3f} "
                           f"predicted={blk['t_predicted_s'] * 1e6:.0f}us "
                           f"measured={blk.get('t_measured_s', 0) * 1e6:.0f}us "
-                          f"pct_of_peak={blk.get('pct_of_peak') or 0:.4%} "
+                          f"pct_of_peak={pct} "
                           f"({blk['dominant']}-bound)")
             except Exception as e:
                 print(f"    roofline: capture failed ({type(e).__name__}: {e})")

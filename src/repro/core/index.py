@@ -566,8 +566,6 @@ class ShardedIndex:
                      has_quant: bool = False, shard_alive=None):
         from jax.sharding import PartitionSpec as P
 
-        from repro.dist.sharding import shard_map_compat
-
         cls, static, shard_size = self.engine_cls, self.static, self.shard_size
         traced_budget = traced
 
@@ -599,8 +597,9 @@ class ShardedIndex:
             in_specs = in_specs + (P("data"),)
         if has_quant:
             in_specs = in_specs + (P("data"), P(), P("data"))
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local, mesh=self.dctx.mesh, in_specs=in_specs, out_specs=P("data"),
+            check_vma=False,
         )
         args = (stacked, Q, budget_vec) + tuple(rest)
         idx, dist, comps = fn(*args)  # (S, B, k) x2, (S, B)

@@ -86,12 +86,18 @@ def dot(x: jax.Array, y: jax.Array) -> jax.Array:
 # Matrix forms — MXU-friendly formulations where possible
 # ---------------------------------------------------------------------------
 
+#: the precision of every f32 distance matmul, here and in the Pallas
+#: kernels: on a TPU the default f32 matmul is a single bf16 pass (~3
+#: significant digits), which would reorder near neighbours in every exact
+#: scan.  Other backends ignore it.
+F32_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def sqeuclidean_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
     """(m, n) squared distances via ``|x|^2 + |y|^2 - 2 x.yT`` (one matmul)."""
     x2 = jnp.sum(X * X, axis=-1)[:, None]
     y2 = jnp.sum(Y * Y, axis=-1)[None, :]
-    d2 = x2 + y2 - 2.0 * (X @ Y.T)
+    d2 = x2 + y2 - 2.0 * jnp.dot(X, Y.T, precision=F32_PRECISION)
     return jnp.maximum(d2, 0.0)
 
 
@@ -111,7 +117,7 @@ def chebyshev_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
 def cosine_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
     Xn = X / jnp.maximum(jnp.linalg.norm(X, axis=-1, keepdims=True), EPS)
     Yn = Y / jnp.maximum(jnp.linalg.norm(Y, axis=-1, keepdims=True), EPS)
-    return 1.0 - Xn @ Yn.T
+    return 1.0 - jnp.dot(Xn, Yn.T, precision=F32_PRECISION)
 
 
 def correlation_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
@@ -131,7 +137,7 @@ def jaccard_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
 
 
 def dot_matrix(X: jax.Array, Y: jax.Array) -> jax.Array:
-    return -(X @ Y.T)
+    return -jnp.dot(X, Y.T, precision=F32_PRECISION)
 
 
 _PAIR: dict[str, Callable] = {

@@ -21,11 +21,13 @@ Two capture surfaces:
   comparison counts to host, which tracers cannot).
 
 Predicted time is the per-chip three-term roofline (``max`` of compute /
-HBM / collective, ``dist/roofline`` constants — a TPU v5p-class hardware
-model; on the CPU CI backend the %-of-peak is honest about being tiny).
-Measured time is the median post-warmup dispatch.  ``pct_of_peak`` =
-predicted / measured: the fraction of the modeled hardware ceiling the
-program actually achieves.
+HBM / collective) against ``dist/roofline.PEAKS``: the peaks of the TPU the
+program runs on, or of the target chip (``roofline.TARGET_KIND``) when it
+runs on anything else.  Measured time is the median post-warmup dispatch.
+``pct_of_peak`` = predicted / measured, the fraction of the hardware
+ceiling the program achieves — only when the program ran on the chip the
+prediction is for.  A CPU run gets ``pct_of_peak = None`` and no gauge: a
+CPU time divided into a TPU prediction measures nothing.
 
 Captured profiles land in a process-wide registry (``profiles()``), as
 telemetry gauges (``roofline_*{program=...}``) when telemetry is on, and
@@ -56,13 +58,14 @@ class ProgramProfile:
     hbm_bytes: float        # loop-aware instruction-output bytes
     intensity: float        # flops / byte
     dot_count: int
-    t_compute_s: float      # flops / PEAK_FLOPS
-    t_memory_s: float       # bytes / HBM_BW
-    t_collective_s: float   # collective bytes / ICI_BW
+    t_compute_s: float      # flops / peak bf16 FLOP/s
+    t_memory_s: float       # bytes / peak HBM bytes/s
+    t_collective_s: float   # collective bytes / peak ICI bytes/s
     t_predicted_s: float    # max of the three terms
     dominant: str           # which term bounds the program
+    peak_kind: str = roofline.TARGET_KIND  # device kind the peaks are of
     t_measured_s: Optional[float] = None
-    pct_of_peak: Optional[float] = None  # predicted / measured
+    pct_of_peak: Optional[float] = None  # predicted / measured, on that chip
 
     def as_row(self) -> dict:
         """The JSON block bench rows carry."""
@@ -74,9 +77,11 @@ class ProgramProfile:
             "dot_count": int(self.dot_count),
             "t_predicted_s": float(self.t_predicted_s),
             "dominant": self.dominant,
+            "peak_kind": self.peak_kind,
         }
         if self.t_measured_s is not None:
             out["t_measured_s"] = float(self.t_measured_s)
+        if self.pct_of_peak is not None:
             out["pct_of_peak"] = float(self.pct_of_peak)
         return out
 
@@ -111,6 +116,7 @@ def export_gauges(prof: ProgramProfile) -> None:
     telem.set_gauge("roofline_predicted_s", prof.t_predicted_s, **labels)
     if prof.t_measured_s is not None:
         telem.set_gauge("roofline_measured_s", prof.t_measured_s, **labels)
+    if prof.pct_of_peak is not None:
         telem.set_gauge("roofline_pct_of_peak", prof.pct_of_peak, **labels)
 
 
@@ -151,9 +157,14 @@ def capture_jit(name: str, fn, *args, labels: Optional[dict] = None,
     hlo = compiled.as_text()
     stats = roofline.hlo_stats(hlo)
     coll = roofline.parse_collectives(hlo)
-    t_compute = stats.flops / roofline.PEAK_FLOPS
-    t_memory = stats.bytes / roofline.HBM_BW
-    t_coll = coll.total_bytes / roofline.ICI_BW
+    dev = jax.devices()[0]
+    chip = roofline.device_peaks(dev)
+    on_chip = chip is not None
+    kind = dev.device_kind if on_chip else roofline.TARGET_KIND
+    chip = chip or roofline.peaks(kind)
+    t_compute = stats.flops / chip.bf16_flops
+    t_memory = stats.bytes / chip.hbm_bytes_s
+    t_coll = coll.total_bytes / chip.ici_bytes_s
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     t_pred = max(terms.values())
@@ -166,8 +177,9 @@ def capture_jit(name: str, fn, *args, labels: Optional[dict] = None,
         dot_count=stats.dot_count,
         t_compute_s=t_compute, t_memory_s=t_memory, t_collective_s=t_coll,
         t_predicted_s=t_pred, dominant=dominant,
+        peak_kind=kind,
         t_measured_s=measured_s,
-        pct_of_peak=(t_pred / measured_s) if measured_s else None,
+        pct_of_peak=(t_pred / measured_s) if (measured_s and on_chip) else None,
     )
     _PROGRAMS[key] = prof
     if export:

@@ -163,6 +163,7 @@ class InfinityIndex:
         X = jnp.asarray(X, jnp.float32)
         n = X.shape[0]
         rng = np.random.default_rng(config.seed)
+        t_start = time.perf_counter()
 
         # 1) projection subset
         if n > config.proj_sample:
@@ -192,6 +193,8 @@ class InfinityIndex:
             D, mask, config.q, num_hops=config.num_hops, impl=config.impl,
             schedule="doubling",
         )
+        jax.block_until_ready(Dq)
+        t_proj = time.perf_counter()
 
         # 3) fit Phi
         ecfg = embed_lib.EmbedConfig(
@@ -229,10 +232,18 @@ class InfinityIndex:
                 phi_params, history, val = params2, hist2, val2
             attempts += 1
         history["validation"] = dict(val, attempts=attempts)
+        t_phi = time.perf_counter()
 
         # 4) embed the full dataset, build the VP tree in embedding space
         Z = embed_lib.apply(phi_params, X)
-        tree = vptree_lib.build_vptree(np.asarray(Z), metric="euclidean", seed=config.seed)
+        Z_host = np.asarray(Z)
+        t_embed = time.perf_counter()
+        tree = vptree_lib.build_vptree(Z_host, metric="euclidean", seed=config.seed)
+        # seconds per build stage, host clock; _flat_view adds "flatten"
+        history["build_s"] = {
+            "projection": t_proj - t_start, "phi": t_phi - t_proj,
+            "embed": t_embed - t_phi, "tree": time.perf_counter() - t_embed,
+        }
         return cls(
             config=config, X=X, Z=Z, phi_params=phi_params, tree=tree,
             train_history=history,
@@ -389,6 +400,7 @@ class InfinityIndex:
         Built on first beam search so snapshots/build cost are unchanged;
         ``refresh`` returns a new instance, which resets it."""
         if self._flat is None:
+            t0 = time.perf_counter()
             flat = vptree_lib.flatten_vptree(
                 self.tree, leaf_size=self.config.leaf_size,
                 Z=np.asarray(self.Z), metric="euclidean",
@@ -396,6 +408,8 @@ class InfinityIndex:
             object.__setattr__(self, "_flat", {
                 "flat": flat, "Zf": self.Z[flat.perm], "zcodes": None,
             })
+            self.train_history.setdefault("build_s", {})["flatten"] = (
+                time.perf_counter() - t0)
         cache = self._flat
         if getattr(self, "quant", None) is not None and cache["zcodes"] is None:
             # bucket scans read EMBEDDING rows, so they need codes of Zf —

@@ -11,18 +11,61 @@ microbatch count the caller knows).
 
 ``parse_collectives`` applies the same loop scaling to collective bytes so
 the collective roofline term sees the per-step traffic, not one iteration's.
+
+The hardware model is ``PEAKS``: published per-chip peaks keyed by the
+``device_kind`` a JAX device reports.  A TPU kind missing from the table is
+an error, never a silent default; a CPU has no entry (``device_peaks``
+returns None) because its numbers are not a roofline of anything deployed.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 import re
 from typing import Optional
 
-# per-chip hardware model (TPU v5p-class): bf16 peak, HBM and ICI bandwidth
-PEAK_FLOPS = 4.59e14
-HBM_BW = 2.76e12
-ICI_BW = 9.0e10
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip (per chip, not per host)."""
+
+    bf16_flops: float  # dense bf16 matmul FLOP/s
+    int8_ops: float  # dense int8 matmul OP/s
+    hbm_bytes_s: float  # HBM bandwidth, bytes/s
+    ici_bytes_s: float  # chip-to-chip interconnect, bytes/s
+    source: str
+
+
+#: device_kind -> peaks.  "TPU v5 lite" is what a TPU v5e reports.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes_s=819e9,
+        ici_bytes_s=1600e9 / 8,  # 1,600 Gbit/s
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+#: the chip the serving path is built for: off-chip predictions (a CPU run,
+#: the multi-pod dry-run) are made for it
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str) -> ChipPeaks:
+    """The peaks of ``kind``; raises for a kind not in ``PEAKS``."""
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {kind!r}; add it to "
+            f"dist/roofline.PEAKS with its source (known: {sorted(PEAKS)})"
+        ) from None
+
+
+def device_peaks(device) -> Optional[ChipPeaks]:
+    """Peaks of a JAX device: None off a TPU, an error for a TPU kind not in
+    the table."""
+    if device.platform != "tpu":
+        return None
+    return peaks(device.device_kind)
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -235,12 +278,14 @@ def hlo_stats(hlo: str, *, default_trip: int = 1) -> HloStats:
 
 def roofline_terms(cost: dict, coll: CollectiveStats, *, chips: int,
                    model_flops: Optional[float] = None) -> dict:
-    """Three-term roofline: compute, HBM, collective — per chip."""
+    """Three-term roofline: compute, HBM, collective — per chip of the
+    ``TARGET_KIND``."""
+    chip = PEAKS[TARGET_KIND]
     flops = float(cost.get("flops", 0.0))
     mem_bytes = float(cost.get("bytes accessed", 0.0))
-    t_compute = flops / PEAK_FLOPS
-    t_memory = mem_bytes / HBM_BW
-    t_collective = coll.total_bytes / ICI_BW
+    t_compute = flops / chip.bf16_flops
+    t_memory = mem_bytes / chip.hbm_bytes_s
+    t_collective = coll.total_bytes / chip.ici_bytes_s
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_collective}
     dominant = max(terms, key=terms.get)
     total = max(t_compute + t_memory + t_collective, 1e-30)

@@ -33,26 +33,6 @@ from repro.models import params as plib
 FSDP_PARAM_THRESHOLD = 1_000_000_000
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the entry point moved (experimental ->
-    top-level) and the replication-check kwarg was renamed (check_rep ->
-    check_vma) in separate releases, so resolve each independently.  Shared
-    by the MoE expert-parallel path and the sharded search engine."""
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    import inspect
-
-    kwarg = (
-        "check_vma" if "check_vma" in inspect.signature(sm).parameters
-        else "check_rep"
-    )
-    return sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{kwarg: False}
-    )
-
-
 @dataclasses.dataclass
 class DistCtx:
     """Mesh + resolved rules for one (arch, mesh, shape) cell."""

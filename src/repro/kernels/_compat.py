@@ -1,17 +1,13 @@
-"""Version compatibility shared by every kernel package.
-
-jax renamed ``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams``; and the
-kernels run compiled on TPU but interpreted elsewhere — both resolved here
-so the policy lives in one place.
+"""Policy shared by every kernel package: the kernels run compiled on TPU
+and interpreted elsewhere, decided here so the rule lives in one place.
 """
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def default_interpret() -> bool:
-    """Kernels compile through Mosaic on TPU, interpret everywhere else."""
+    """Kernels compile through Mosaic on TPU, interpret everywhere else.
+    Called per kernel call, never at import: asking for the backend starts
+    it, and a process must be able to import the kernels before it does."""
     return jax.default_backend() != "tpu"

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _bag_kernel(ids_ref, w_ref, table_ref, o_ref, *, s_steps: int):
     s = pl.program_id(1)
@@ -67,7 +65,7 @@ def embedding_bag_pallas(
         functools.partial(_bag_kernel, s_steps=S),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

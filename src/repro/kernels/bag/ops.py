@@ -4,11 +4,9 @@ single-device fast path; the jnp path composes with shard_map)."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels._compat import default_interpret
 from repro.kernels.bag.bag import embedding_bag_pallas
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def embedding_bag(
@@ -21,7 +19,7 @@ def embedding_bag(
 ) -> jax.Array:
     if impl == "pallas":
         return embedding_bag_pallas(
-            table, ids, weights, combine=combine, interpret=_INTERPRET
+            table, ids, weights, combine=combine, interpret=default_interpret()
         )
     from repro.kernels.bag.ref import embedding_bag_ref
 

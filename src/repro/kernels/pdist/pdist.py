@@ -9,12 +9,14 @@ regimes (DESIGN.md §4):
   clamp, sqrt / cosine normalize) is fused into the final k-step so the
   distance matrix is written to HBM exactly once.
 * elementwise family (manhattan / chebyshev):
-  (bm, bk, bn) |x - y| cube reduced on the VPU, accumulated directly into the
-  output tile across k-steps.
+  |x - y| cube reduced on the VPU, accumulated directly into the output tile
+  across k-steps.
 
 grid = (m/bm, n/bn, d/bk), k innermost.  Defaults (128, 128, 128) keep every
-tile lane-aligned; the cube path drops bk to 32 to bound the VMEM cube at
-128*32*128*4B = 2 MiB.
+tile lane-aligned, which Mosaic requires of the d-block for both families.
+The cube family reduces its d-block in ``CUBE_SUB``-wide slices
+(``cube_accumulate``), which bounds the VMEM cube at
+128*32*128*4B = 2 MiB whatever the d-block.
 """
 from __future__ import annotations
 
@@ -25,12 +27,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.core.metrics import F32_PRECISION
 from repro.kernels._compat import default_interpret
 
 EPS = 1e-12
 _MATMUL = ("sqeuclidean", "euclidean", "cosine", "dot")
 _CUBE = ("manhattan", "chebyshev")
+#: d-slice width of the elementwise family's (bm, CUBE_SUB, bn) VMEM cube
+CUBE_SUB = 32
+
+
+def cube_accumulate(acc, x, y, *, metric: str):
+    """Fold one (bm, bk) x (bn, bk) d-block into the (bm, bn) manhattan /
+    chebyshev accumulator, ``CUBE_SUB`` lanes of d at a time so the
+    (bm, CUBE_SUB, bn) |x - y| cube stays bounded (shared with
+    ``kernels/topk``)."""
+    yt = y.T  # (bk, bn)
+    for c in range(0, x.shape[1], CUBE_SUB):
+        xs = x[:, c : c + CUBE_SUB]  # (bm, CUBE_SUB)
+        ys = yt[c : c + CUBE_SUB, :]  # (CUBE_SUB, bn)
+        cube = jnp.abs(xs[:, :, None] - ys[None, :, :])  # (bm, CUBE_SUB, bn)
+        if metric == "manhattan":
+            acc = acc + jnp.sum(cube, axis=1)
+        else:  # chebyshev
+            acc = jnp.maximum(acc, jnp.max(cube, axis=1))
+    return acc
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc, sx, sy, *, metric: str, k_steps: int):
@@ -45,7 +66,8 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc, sx, sy, *, metric: str, k_steps: in
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     y = y_ref[...].astype(jnp.float32)  # (bn, bk)
     acc[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_PRECISION,
     )
     sx[...] += jnp.sum(x * x, axis=1, keepdims=True)
     sy[...] += jnp.sum(y * y, axis=1, keepdims=True)
@@ -71,13 +93,10 @@ def _cube_kernel(x_ref, y_ref, o_ref, *, metric: str, k_steps: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    y = y_ref[...].astype(jnp.float32)  # (bn, bk)
-    cube = jnp.abs(x[:, :, None] - y.T[None, :, :])  # (bm, bk, bn)
-    if metric == "manhattan":
-        o_ref[...] += jnp.sum(cube, axis=1)
-    else:  # chebyshev
-        o_ref[...] = jnp.maximum(o_ref[...], jnp.max(cube, axis=1))
+    o_ref[...] = cube_accumulate(
+        o_ref[...], x_ref[...].astype(jnp.float32),
+        y_ref[...].astype(jnp.float32), metric=metric,
+    )
 
 
 @functools.partial(
@@ -100,8 +119,6 @@ def pdist_pallas(
     m, d = X.shape
     n, d2 = Y.shape
     assert d == d2, (X.shape, Y.shape)
-    if metric in _CUBE:
-        bk = min(bk, 32)
 
     pm, pn, pk = (-m) % bm, (-n) % bn, (-d) % bk
     # zero padding in d is exact for every supported metric; padded rows are
@@ -119,7 +136,7 @@ def pdist_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

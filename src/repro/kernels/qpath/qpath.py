@@ -7,17 +7,19 @@ one path-doubling sweep is ``M <- min(M, M (*) M)`` where
 
 with combine in {+, max, logaddexp}.  A (min, +) semiring product has no MXU
 mapping (it is not a ring), so the kernel is VPU-bound by design; the tiling
-goal is to keep the (bm, bk, bn) combine cube resident in VMEM and stream k
-tiles from HBM exactly once per (i, j) output tile.
+goal is to keep the combine cube resident in VMEM and stream k tiles from
+HBM exactly once per (i, j) output tile.
 
 Tiling
 ------
 grid = (m/bm, n/bn, k/bk), k innermost ("arbitrary" semantics) so the output
 tile acts as the running-min accumulator across k steps.  Default tile
-(bm, bn, bk) = (128, 128, 8): the combine cube is 128*8*128*4B = 512 KiB and
-the A/B tiles are lane-aligned (last dim 128).  bk is the sublane axis of the
-broadcast — kept small so cube + tiles + accumulator fit comfortably in the
-~16 MiB of VMEM alongside double buffering.
+(bm, bn, bk) = (128, 128, 128): every A/B block is lane-aligned (last dim a
+multiple of 128, which Mosaic requires).  Inside a step the k-block is
+reduced in ``SUB``-wide slices, so the combine cube is
+(bm, SUB, bn) = 128*8*128*4B = 512 KiB rather than the 8 MiB a whole
+(bm, bk, bn) cube would take — tiles + cube + accumulator fit the ~16 MiB
+of VMEM alongside double buffering.
 """
 from __future__ import annotations
 
@@ -28,8 +30,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels._compat import default_interpret
+
+#: k-slice width of the (bm, SUB, bn) combine cube; ``bk`` is a multiple of it
+SUB = 8
 
 
 def _combine(a, b, mode: str):
@@ -42,18 +46,18 @@ def _combine(a, b, mode: str):
     raise ValueError(mode)
 
 
-def _qpath_kernel(a_ref, b_ref, o_ref, *, mode: str, k_steps: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
+def _qpath_kernel(a_ref, b_ref, o_ref, *, mode: str):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, jnp.inf)
 
-    a = a_ref[...]  # (bm, bk)
-    b = b_ref[...]  # (bk, bn)
-    cube = _combine(a[:, :, None], b[None, :, :], mode)  # (bm, bk, bn)
-    tile_min = jnp.min(cube, axis=1)  # (bm, bn)
-    o_ref[...] = jnp.minimum(o_ref[...], tile_min)
+    acc = o_ref[...]  # (bm, bn)
+    for c in range(0, a_ref.shape[1], SUB):
+        a = a_ref[:, c : c + SUB]  # (bm, SUB)
+        b = b_ref[c : c + SUB, :]  # (SUB, bn)
+        cube = _combine(a[:, :, None], b[None, :, :], mode)  # (bm, SUB, bn)
+        acc = jnp.minimum(acc, jnp.min(cube, axis=1))
+    o_ref[...] = acc
 
 
 @functools.partial(
@@ -66,11 +70,12 @@ def qpath_matmul_pallas(
     mode: str = "minmax",
     bm: int = 128,
     bn: int = 128,
-    bk: int = 8,
+    bk: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Semiring matmul via pallas_call.  Shapes padded to tile multiples with
-    +inf (the min identity), so arbitrary (m, k) x (k, n) are supported."""
+    +inf (the min identity), so arbitrary (m, k) x (k, n) are supported.
+    ``bk`` must be a multiple of ``SUB``."""
     if interpret is None:
         interpret = default_interpret()
     m, kdim = A.shape
@@ -87,7 +92,7 @@ def qpath_matmul_pallas(
     grid = (M // bm, N // bn, K // bk)
 
     out = pl.pallas_call(
-        functools.partial(_qpath_kernel, mode=mode, k_steps=grid[2]),
+        functools.partial(_qpath_kernel, mode=mode),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -95,7 +100,7 @@ def qpath_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

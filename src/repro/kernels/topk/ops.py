@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the fused distance + top-k kernel.
 
-Resolves interpret-vs-compiled from the backend (like ``pdist/ops``) and
-picks tile sizes from the problem shape (m, n, d, k) with the same
-lane-alignment rules as ``pdist``: 128-wide tiles, the elementwise-family
-d-tile dropped to 32 to bound the VMEM cube, the int8 regime's query tile
-sublane-aligned to the int8 minimum (32).
+Resolves interpret-vs-compiled from the backend on every call (like
+``pdist/ops``) and picks tile sizes from the problem shape (m, n, d, k) with
+the same lane-alignment rules as ``pdist``: 128-wide tiles (or the whole of
+a narrower d), the int8 regime's query tile sublane-aligned to the int8
+minimum (32).
 
 ``topk`` serves the f32 regimes (now including masked scans — the ``valid``
 operand); ``topk_quant`` serves the int8 corpus-code regime fed by
@@ -24,8 +24,6 @@ from repro.kernels.topk.topk import (
     topk_pallas,
     topk_quant_pallas,
 )
-
-_INTERPRET = default_interpret()
 
 __all__ = [
     "topk", "topk_quant", "tile_config", "SUPPORTED", "MATMUL_METRICS",
@@ -47,15 +45,15 @@ def tile_config(m: int, n: int, d: int, k: int, metric: str,
     * bn: 128 by default; doubled for dataset-dominated MXU scans
       (n >= 64K) so the per-tile merge amortizes over more candidates.  The
       cube family keeps bn = 128 — widening it would blow the 2 MiB bound
-      on the (bm, bk, bn) VPU intermediate.
-    * bk: 128 for the MXU families (f32 and int8), 32 for the VPU cube
-      family (bounds the (bm, bk, bn) cube at 2 MiB), shrunk for low-d data.
+      on the (bm, 32, bn) VPU intermediate.
+    * bk: 128, or the whole (8-rounded) d when it is narrower — either way
+      lane-aligned as Mosaic requires.  The VPU cube family reduces its
+      d-block in 32-wide slices inside the kernel, which bounds the cube.
     """
     sub = 32 if quantized else 8
     bm = min(128, _round_up(max(m, 1), sub))
     bn = 256 if (n >= 65536 and metric not in CUBE_METRICS) else 128
-    bk = 32 if metric in CUBE_METRICS else 128
-    bk = min(bk, _round_up(max(d, 1), 8))
+    bk = min(128, _round_up(max(d, 1), 8))
     return dict(bm=bm, bn=bn, bk=bk)
 
 
@@ -71,7 +69,7 @@ def topk(
     cfg = tile_config(X.shape[0], Y.shape[0], X.shape[1], k, metric)
     return topk_pallas(
         X, Y, k=k, metric=metric, exclude_self=exclude_self, valid=valid,
-        interpret=_INTERPRET, **cfg,
+        interpret=default_interpret(), **cfg,
     )
 
 
@@ -96,5 +94,5 @@ def topk_quant(
     )
     return topk_quant_pallas(
         Q, codes, scales, sqnorms, k=k, metric=metric, valid=valid,
-        interpret=_INTERPRET, **cfg,
+        interpret=default_interpret(), **cfg,
     )

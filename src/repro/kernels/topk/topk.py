@@ -13,7 +13,8 @@ Structure (grid = (m/bm, n/bn, d/bk), d innermost, n-then-d "arbitrary"):
 * distance tile — same two regimes as ``kernels/pdist``: the matmul family
   (sqeuclidean / euclidean / cosine / dot) accumulates the MXU cross term +
   squared norms in f32 scratch across d-tiles; the elementwise family
-  (manhattan / chebyshev) reduces the (bm, bk, bn) |x-y| cube on the VPU.
+  (manhattan / chebyshev) reduces the |x-y| cube on the VPU, in d-slices
+  (``pdist.cube_accumulate``) so the cube stays at 2 MiB.
 * streaming selection — at the last d-step the finished (bm, bn) tile is
   merged into the (bm, k) running top-k by k rounds of masked min-extraction
   over the (bm, k + bn) concatenation (a partition merge: each round peels
@@ -55,8 +56,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant as quant_lib
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.core.metrics import F32_PRECISION
 from repro.kernels._compat import default_interpret
+from repro.kernels.pdist.pdist import cube_accumulate
 
 EPS = 1e-12
 MATMUL_METRICS = ("sqeuclidean", "euclidean", "cosine", "dot")
@@ -144,7 +146,8 @@ def _matmul_kernel(*refs, metric: str, k: int, n: int, k_steps: int,
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     y = y_ref[...].astype(jnp.float32)  # (bn, bk)
     acc[...] += jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_PRECISION,
     )
     sx[...] += jnp.sum(x * x, axis=1, keepdims=True)
     sy[...] += jnp.sum(y * y, axis=1, keepdims=True)
@@ -187,13 +190,10 @@ def _cube_kernel(*refs, metric: str, k: int, n: int, k_steps: int,
     def _init_dist():
         dist[...] = jnp.zeros_like(dist)
 
-    x = x_ref[...].astype(jnp.float32)  # (bm, bk)
-    y = y_ref[...].astype(jnp.float32)  # (bn, bk)
-    cube = jnp.abs(x[:, :, None] - y.T[None, :, :])  # (bm, bk, bn)
-    if metric == "manhattan":
-        dist[...] += jnp.sum(cube, axis=1)
-    else:  # chebyshev
-        dist[...] = jnp.maximum(dist[...], jnp.max(cube, axis=1))
+    dist[...] = cube_accumulate(
+        dist[...], x_ref[...].astype(jnp.float32),
+        y_ref[...].astype(jnp.float32), metric=metric,
+    )
 
     @pl.when(ks == k_steps - 1)
     def _epilogue():
@@ -267,7 +267,7 @@ def _call_common(M, N, grid, k, bm, bn, bk, interpret):
             jax.ShapeDtypeStruct((M, k), jnp.float32),
             jax.ShapeDtypeStruct((M, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
@@ -318,8 +318,6 @@ def topk_pallas(
     n, d2 = Y.shape
     assert d == d2, (X.shape, Y.shape)
     k = int(k)
-    if metric in CUBE_METRICS:
-        bk = min(bk, 32)
 
     pm, pn, pk = (-m) % bm, (-n) % bn, (-d) % bk
     Xp = jnp.pad(X, ((0, pm), (0, pk)))

@@ -1075,6 +1075,9 @@ def main() -> None:
             print(f"{name:10s} {summary}")
         return
 
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
     flt = json.loads(args.filter) if args.filter else None
     X = synthetic.make("manifold", args.n + args.queries, seed=0)
     if args.snapshot and os.path.exists(os.path.join(args.snapshot, "meta.json")):

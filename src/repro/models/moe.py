@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import LMConfig
-from repro.dist.sharding import shard_map_compat as _shard_map
 
 
 MOE_CHUNK_TOKENS = 32768  # gathered tokens processed per EP chunk
@@ -240,11 +239,12 @@ def moe_ffn_ep(
     bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
     x_spec = P(bspec, None, None)
     _, wspecs = expert_weight_specs(cfg, mesh, model_axis=model_axis, data_axis=data_axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(x_spec, x_spec, wspecs["wg"], wspecs["wu"], wspecs["wd"]),
         out_specs=x_spec,
+        check_vma=False,
     )
     return fn(x, probs, p["wg"], p["wu"], p["wd"])
 
@@ -290,10 +290,11 @@ def moe_ffn_ep_zero3(
     bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
     x_spec = P(bspec, None, None)
     e_spec = P(model_axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(x_spec, x_spec, e_spec, e_spec, e_spec),
         out_specs=x_spec,
+        check_vma=False,
     )
     return fn(x, probs, p["wg"], p["wu"], p["wd"])

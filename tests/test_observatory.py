@@ -33,6 +33,7 @@ from repro.core import probes as probes_lib
 from repro.core import profile as profile_lib
 from repro.core import scan as scan_lib
 from repro.core import telemetry as telem
+from repro.dist import roofline
 from repro.launch.serve import SearchServer
 
 N, D, K = 256, 16, 10
@@ -271,10 +272,13 @@ def test_capture_jit_topk_scan_profile(data):
                                    labels={"n": N, "k": 8})
     assert prof.flops > 0 and prof.hbm_bytes > 0
     assert prof.t_predicted_s > 0 and prof.t_measured_s > 0
-    assert prof.pct_of_peak > 0
+    # a CPU time divided into a TPU prediction is no share of any peak
+    assert prof.pct_of_peak is None
+    assert prof.peak_kind == roofline.TARGET_KIND
     assert prof.dominant in ("compute", "memory", "collective")
     text = telem.metrics_text()
-    assert "roofline_pct_of_peak{" in text
+    assert "roofline_predicted_s{" in text
+    assert "roofline_pct_of_peak{" not in text
     # re-capture returns the cached profile (no recompilation)
     again = profile_lib.capture_jit("topk:test", fn, Q, X,
                                     labels={"n": N, "k": 8})
@@ -310,7 +314,32 @@ def test_server_capture_roofline(data):
     (name, blk), = out.items()
     assert name == "search:brute"
     assert blk["flops"] > 0 and blk["t_predicted_s"] > 0
-    assert blk["pct_of_peak"] > 0
+    assert blk.get("pct_of_peak") is None
+
+
+def test_v5e_device_kind_resolves_to_its_published_peaks():
+    chip = roofline.peaks("TPU v5 lite")
+    assert chip.bf16_flops == 197e12 and chip.int8_ops == 393e12
+    assert chip.hbm_bytes_s == 819e9 and chip.ici_bytes_s == 1600e9 / 8
+    assert "TPU v5e" in chip.source
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    assert roofline.device_peaks(Dev()) is chip
+
+
+def test_unknown_tpu_kind_raises_and_cpu_has_no_peaks():
+    class Dev:
+        platform, device_kind = "tpu", "TPU v99"
+
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline.device_peaks(Dev())
+
+    class Cpu:
+        platform, device_kind = "cpu", "cpu"
+
+    assert roofline.device_peaks(Cpu()) is None
 
 
 # ---------------------------------------------------------------------------
