@@ -58,7 +58,8 @@ def stage_breakdown(engine: str, repeats: int = 1) -> dict:
     """Per-stage ``{comparisons, ms}`` for ``engine`` from the telemetry
     registry (DESIGN.md §16) — the q-sweep's answer to WHERE higher q
     saves work: traversal vs centroid ranking vs bucket scan vs rerank
-    comparisons and milliseconds, averaged over ``repeats`` timed runs.
+    comparisons, and the host milliseconds of the stages that have a span
+    (embed, traversal, rerank), averaged over ``repeats`` timed runs.
     Callers ``telem.reset()`` before the timed region so the window is one
     cell's; returns {} when telemetry is disabled."""
     from repro.core import telemetry as telem

@@ -39,9 +39,11 @@ Prometheus text exposition at ``http://127.0.0.1:PORT/metrics`` from a
 stdlib ``http.server`` thread for the whole run (DESIGN.md §16);
 ``--hold-metrics SECONDS`` keeps the process (and the endpoint) alive
 after the sweep so a scraper can collect the final counters, and
-``--trace-out PATH`` writes the bounded trace ring as Chrome/Perfetto
-``trace_event`` JSON on exit — load it at ui.perfetto.dev for the
-per-stage flamegraph.
+``--trace-out DIR`` runs the JAX profiler over the whole run and writes
+its trace under ``DIR`` (``plugins/profile/<run>/<host>.xplane.pb``): the
+program's spans (``pad``, ``dispatch``, ``embed``, ``traversal``, ...)
+and the device's operations on one clock — open it in TensorBoard's
+profile plugin or xprof.
 
 ``--deadline-ms`` / ``--chaos`` exercise fault-tolerant serving
 (DESIGN.md §14): ``--chaos JSON`` arms a deterministic
@@ -93,12 +95,14 @@ scraper sees the overload series next to the latency/quality/efficiency
 ones (CI greps exactly these).
 """
 import argparse
+import glob
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax
 import numpy as np
 
 from benchmarks.common import recall_at_k
@@ -181,9 +185,10 @@ def main() -> None:
                     help="keep the process (and /metrics) alive this long "
                          "after the sweep so a scraper can collect the "
                          "final counters")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the telemetry trace ring as Chrome/Perfetto "
-                         "trace_event JSON on exit (enables telemetry)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="run the JAX profiler over the whole run and write "
+                         "its trace (program spans and device operations) "
+                         "under DIR")
     ap.add_argument("--probe-rate", type=float, default=None, metavar="R",
                     help="shadow this fraction of served queries through "
                          "the exact oracle: sliding-window recall@k with "
@@ -203,8 +208,12 @@ def main() -> None:
                          "and export roofline_* gauges")
     args = ap.parse_args()
 
-    if args.metrics_port is not None or args.trace_out:
+    if args.metrics_port is not None:
         telem.enable()
+    if args.trace_out:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # the program's own spans suffice
+        jax.profiler.start_trace(args.trace_out, profiler_options=opts)
     if args.metrics_port is not None:
         host, port = start_metrics_server(args.metrics_port)
         print(f"metrics: http://{host}:{port}/metrics", flush=True)
@@ -406,7 +415,11 @@ def main() -> None:
               f"batches={rs['batches']} breaker={rs['breaker_state']}")
 
     if args.trace_out:
-        print(f"trace -> {telem.dump_trace(args.trace_out)}", flush=True)
+        jax.profiler.stop_trace()
+        written = sorted(glob.glob(os.path.join(
+            args.trace_out, "plugins", "profile", "*", "*.xplane.pb")),
+            key=os.path.getmtime)
+        print(f"trace -> {written[-1]}", flush=True)
     if args.metrics_port is not None and args.hold_metrics > 0:
         import time as time_lib
 

@@ -36,6 +36,7 @@ from repro.core import knn_graph as knn_lib
 from repro.core import metrics as metrics_lib
 from repro.core import quant as quant_lib
 from repro.core import scan as scan_lib
+from repro.core import telemetry as telem
 from repro.core.index import SearchResult
 
 
@@ -44,6 +45,7 @@ from repro.core.index import SearchResult
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "block", "impl"))
+@telem.stage_scope("scan")
 def brute_force(
     X: jax.Array, Q: jax.Array, *, k: int = 1, metric: str = "euclidean",
     block: int = 0, impl: str = "jnp", valid: Optional[jax.Array] = None,
@@ -73,6 +75,7 @@ def brute_force(
 @functools.partial(
     jax.jit, static_argnames=("k", "K", "metric", "block", "impl")
 )
+@telem.stage_scope("scan")
 def _brute_quant_search(
     Q, codes, scales, sqnorms, X, *, k, K, metric, block, impl, valid=None,
 ) -> SearchResult:
@@ -133,18 +136,19 @@ class BruteIndex:
         )
         Q = jnp.asarray(Q, jnp.float32)
         k = int(k)
-        if self.quant is not None:
-            codes, scales, sqnorms = self.quant.device_view()
-            return _brute_quant_search(
-                Q, codes, scales, sqnorms, self.X, k=k,
-                K=quant_lib.shortlist_width(k, self.X.shape[0]),
-                metric=self.metric, block=self.block, impl=self.impl,
-                valid=mask,
+        with telem.span("scan", engine="brute"):
+            if self.quant is not None:
+                codes, scales, sqnorms = self.quant.device_view()
+                return _brute_quant_search(
+                    Q, codes, scales, sqnorms, self.X, k=k,
+                    K=quant_lib.shortlist_width(k, self.X.shape[0]),
+                    metric=self.metric, block=self.block, impl=self.impl,
+                    valid=mask,
+                )
+            return brute_force(
+                self.X, Q, k=k, metric=self.metric,
+                block=self.block, impl=self.impl, valid=mask,
             )
-        return brute_force(
-            self.X, Q, k=k, metric=self.metric,
-            block=self.block, impl=self.impl, valid=mask,
-        )
 
     def memory_bytes(self) -> int:
         return index_lib.pytree_nbytes(self.X) + index_lib.side_store_bytes(self)

@@ -556,8 +556,6 @@ class ShardedIndex:
         with telem.span("shard_dispatch", engine=self.engine,
                         shards=S_total):
             idx, dist, comps = fn(*args)
-            if telem.enabled():
-                jax.block_until_ready(comps)
         return SearchResult(idx, dist, comps)
 
     def _search_impl(self, stacked, Q, budget_vec, *rest, k: int,

@@ -656,8 +656,6 @@ class LiveIndex:
         telem.count("live_scan_total", engine=self.engine, segment="frozen")
         with telem.span("frozen_scan", engine=self.engine, oversample=kf):
             fres = gen.frozen.search(Q, k=kf, budget=budget, filter=f_filter)
-            if telem.enabled():
-                jax.block_until_ready(fres.comparisons)
 
         kd = min(k, self.delta_cap)
         delta_valid = alive_d if mask is None else (
@@ -676,8 +674,6 @@ class LiveIndex:
                 Q, fres.idx, gen.frozen_X, tomb_f, delta_X, delta_valid, quant,
                 k=k, kd=kd, kq=kq or 0, metric=self.metric,
             )
-            if telem.enabled():
-                jax.block_until_ready(midx)
         # frozen work as counted by the engine + one comparison per alive
         # (and passing, under a filter) delta row — the scan really scores
         # each of them (on codes when quantized, plus the kq exact rescores)

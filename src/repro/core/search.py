@@ -41,34 +41,11 @@ from repro.core import vptree as vptree_lib
 from repro.core.index import SearchResult
 
 
-def _note_stages(engine: str, qv: float, dt_s: float, stages: dict) -> None:
-    """Record the beam's jit-threaded stage counters (DESIGN.md §16).
-
-    The three traversal stages run inside ONE fused dispatch, so their
-    wall-clock split cannot be measured on the host — each stage's span
-    duration is the dispatch time apportioned by its comparison share,
-    flagged ``estimated`` in the trace args.  Counters are exact."""
-    if not telem.enabled():
-        return
-    vals = {name: int(np.asarray(arr).sum()) for name, arr in stages.items()}
-    total = sum(vals.values())
-    qs = telem.q_label(qv)
-    ts = telem.now_us() - dt_s * 1e6
-    for name, v in vals.items():
-        telem.count("comparisons_total", v, engine=engine, stage=name, q=qs)
-        share = dt_s * (v / total) if total else 0.0
-        telem.emit_span(name, share, ts_us=ts, engine=engine,
-                        args={"comparisons": v, "estimated": True})
-        ts += share * 1e6
-
-
 def _note_comps(engine: str, stage: str, qv: float, comps) -> None:
-    """Count a branch's total comparisons (syncs the device scalar — only
-    when telemetry is enabled, so the disabled path never blocks)."""
-    if not telem.enabled():
-        return
-    telem.count("comparisons_total", int(np.asarray(comps).sum()),
-                engine=engine, stage=stage, q=telem.q_label(qv))
+    """Count a stage's comparisons from its device array: added when the
+    counter is read, so the search never waits for it (DESIGN.md §16)."""
+    telem.count("comparisons_total", comps, engine=engine, stage=stage,
+                q=telem.q_label(qv))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,8 +284,6 @@ class InfinityIndex:
         Q = jnp.asarray(Q, jnp.float32)
         with telem.span("embed", engine="infinity"):
             Zq = embed_lib.apply(self.phi_params, Q)
-            if telem.enabled():
-                jax.block_until_ready(Zq)
         K = max(k, rerank)
         if mask is not None and rerank:
             # two-stage under a filter: widen the candidate stage by
@@ -324,8 +299,6 @@ class InfinityIndex:
                 bi, bd, comps = vptree_lib.descend_infty(
                     self.tree, Zq, X=self.Z, metric="euclidean"
                 )
-                if telem.enabled():
-                    jax.block_until_ready(comps)
             _note_comps("infinity", "traversal", self.config.q, comps)
             idx = bi[:, None]
         elif self._use_beam(mode, Q.shape[0]):
@@ -337,18 +310,16 @@ class InfinityIndex:
                 K = max(K, quant_lib.shortlist_width(k, self.X.shape[0], mult=8))
             flat, Zf, zc = self._flat_view()
             codes, scales = zc if zc is not None else (None, None)
-            t0 = time.perf_counter()
-            idx, _, comps, stages = vptree_lib.search_beam(
-                flat, Zq, q=self.config.q, k=K, X=Zf, metric="euclidean",
-                max_comparisons=None if max_comparisons is None
-                else int(max_comparisons),
-                beam_width=beam_width, bucket_cap=bucket_cap, valid=mask,
-                codes=codes, scales=scales, with_stages=True,
-            )
-            if telem.enabled():
-                jax.block_until_ready(comps)
-                _note_stages("infinity", self.config.q,
-                             time.perf_counter() - t0, stages)
+            with telem.span("traversal", engine="infinity", mode="beam"):
+                idx, _, comps, stages = vptree_lib.search_beam(
+                    flat, Zq, q=self.config.q, k=K, X=Zf, metric="euclidean",
+                    max_comparisons=None if max_comparisons is None
+                    else int(max_comparisons),
+                    beam_width=beam_width, bucket_cap=bucket_cap, valid=mask,
+                    codes=codes, scales=scales, with_stages=True,
+                )
+            for name, stage_comps in stages.items():
+                _note_comps("infinity", name, self.config.q, stage_comps)
         else:
             with telem.span("traversal", engine="infinity", mode="best_first"):
                 idx, _, comps = vptree_lib.search_best_first(
@@ -356,27 +327,23 @@ class InfinityIndex:
                     metric="euclidean",
                     max_comparisons=max_comparisons, valid=mask,
                 )
-                if telem.enabled():
-                    jax.block_until_ready(comps)
             _note_comps("infinity", "traversal", self.config.q, comps)
         if rerank and K > k:
             with telem.span("rerank", engine="infinity"):
                 idx, dists = self._rerank(Q, idx, k)
-                if telem.enabled():
-                    jax.block_until_ready(idx)
-            # each reranked candidate costs one original-metric comparison
-            if telem.enabled():
-                telem.count("comparisons_total", int(K) * int(idx.shape[0]),
-                            engine="infinity", stage="rerank",
-                            q=telem.q_label(self.config.q))
-            comps = comps + K
+                # each reranked candidate costs one original-metric comparison
+                comps = comps + K
+            telem.count("comparisons_total", int(K) * int(idx.shape[0]),
+                        engine="infinity", stage="rerank",
+                        q=telem.q_label(self.config.q))
         else:
             # same scan-engine path as the rerank branch: the k survivors are
             # scored in the ORIGINAL metric and returned ascending.  comps
             # keeps counting tree visits only (embedding-space evaluations);
             # the k final scores are reporting, not search work — the
             # paper's accounting, see the SearchResult caveat in core/index.
-            idx, dists = self._rerank(Q, idx[:, :k], k)
+            with telem.span("rerank", engine="infinity"):
+                idx, dists = self._rerank(Q, idx[:, :k], k)
         return SearchResult(idx, dists, comps)
 
     @staticmethod

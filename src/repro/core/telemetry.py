@@ -1,12 +1,11 @@
-"""Search telemetry subsystem: counters, histograms, spans, trace export
-(DESIGN.md §16).
+"""Search telemetry subsystem: counters, histograms, spans (DESIGN.md §16).
 
-One process-wide, dependency-free registry answering the question the flat
-``stats()`` dict cannot: *which stage* of a query spent the comparisons and
-the milliseconds.  In metric-space search the budget currency is distance
+One process-wide registry answering the question the flat ``stats()``
+dict cannot: *which stage* of a query spent the comparisons and the
+milliseconds.  In metric-space search the budget currency is distance
 evaluations (the paper's App. F.1 accounting), so the registry is built
 around labeled counters — ``comparisons_total{engine=...,stage=...,q=...}``
-— next to log-spaced latency histograms and a bounded in-memory trace ring.
+— next to log-spaced latency histograms.
 
 Three primitives:
 
@@ -15,31 +14,35 @@ Three primitives:
   (``LATENCY_BUCKETS_S``) so two runs' distributions are always mergeable.
   Use through the convenience entry points ``count`` / ``set_gauge`` /
   ``observe``, which are no-ops (one branch) while telemetry is disabled.
-* ``span(name, **labels)`` — a context manager that times a stage, records
-  the duration into the ``stage_seconds`` histogram (labeled
-  ``stage=name``) and appends a Chrome ``trace_event`` to the trace ring.
-  The span closes — histogram observed, trace event emitted, flagged
-  ``error=True`` — even when the body raises, so exception paths never
-  leak an open span.  ``emit_span`` records a stage whose duration was
-  measured (or apportioned) by the caller — how the in-kernel beam stages,
-  whose comparison counters exit the jitted program as extra scalar
-  outputs, get flamegraph rows without host callbacks.
-* the trace ring — a fixed-capacity ring of ``trace_event`` dicts,
-  exported by ``dump_trace(path)`` as Chrome/Perfetto-loadable JSON.
-  Overflow overwrites the oldest events (``dropped`` is reported), so
-  sustained traffic holds memory flat.
+  ``count`` also takes a device array: its sum is added when the counter
+  is read (or once the array is ready), so counting never waits on the
+  device.
+* ``span(name, **labels)`` — a context manager around one stage of host
+  work.  It always opens a ``jax.profiler.TraceAnnotation`` of that name
+  and labels while the profiler collects, so the span lands on the
+  ``/host:CPU`` plane on the same clock as the device's operations; with
+  telemetry enabled it also records the wall time into the
+  ``stage_seconds`` histogram (labeled ``stage=name``).  The span closes —
+  histogram observed, annotation ended and flagged ``error=True`` — even
+  when the body raises.
+* ``stage_scope(name)`` — the device half of a stage: a decorator that
+  traces a jitted body under ``jax.named_scope(name)``, so the stage's
+  name sits in the HLO metadata of its operations.
 
 Global switch: ``enable()`` / ``disable()`` (or env ``REPRO_TELEMETRY=1``).
-Disabled, every entry point returns after a single flag branch — no locks,
-no allocation — and instrumented code paths are behavior-identical
-(bit-exact search ids) to an uninstrumented build: recording only observes
-values the search already computed.
+Disabled, every registry entry point returns after a single flag branch,
+and a span without a running profiler costs one check; instrumented
+code paths are behavior-identical (bit-exact search ids) either way, and
+neither state waits on the device: recording only observes values the
+search already computed.
 
 Exposition: ``metrics_text()`` renders the registry in Prometheus text
 exposition format (``search_latency_bucket{le=...}``,
 ``comparisons_total{stage=...}``, ...); ``snapshot()`` returns the same
 data as a nested dict (what ``SearchServer.stats()['telemetry']`` and the
-``BENCH_*.json`` stamps embed).
+``BENCH_*.json`` stamps embed).  Timelines come from the JAX profiler
+(``jax.profiler.start_trace``), which holds the spans and the device's
+operations together.
 
 Naming note: this module is ``repro.core.telemetry`` and nothing else —
 ``repro.core.metrics`` is the *dissimilarity* registry (euclidean, cosine,
@@ -48,19 +51,21 @@ name.
 """
 from __future__ import annotations
 
-import json
+import functools
 import os
 import threading
 import time
 from typing import Optional
 
+import jax
+import numpy as np
+
 __all__ = [
     "LATENCY_BUCKETS_S", "Counter", "Gauge", "Histogram", "Registry",
     "REGISTRY", "enabled", "enable", "disable", "reset",
-    "count", "set_gauge", "observe", "span", "emit_span",
+    "count", "set_gauge", "observe", "span", "stage_scope",
     "counter_series", "histogram_series", "counter_total",
-    "snapshot", "summary", "metrics_text", "dump_trace",
-    "trace_events", "set_trace_cap", "now_us", "q_label",
+    "snapshot", "summary", "metrics_text", "q_label",
 ]
 
 #: fixed log-spaced latency buckets (seconds): 100us .. 10s in a
@@ -73,7 +78,8 @@ LATENCY_BUCKETS_S = (
 
 _ENABLED = os.environ.get("REPRO_TELEMETRY", "") not in ("", "0", "false")
 _LOCK = threading.RLock()
-_T0 = time.perf_counter()  # trace timestamps are microseconds since import
+_Annotation = jax.profiler.TraceAnnotation
+_collecting = _Annotation.is_enabled  # is a profiler trace being taken
 
 
 def enabled() -> bool:
@@ -101,29 +107,52 @@ def _label_str(key: tuple) -> str:
 
 
 class Counter:
-    """Monotonic labeled counter."""
+    """Monotonic labeled counter.
+
+    ``inc`` takes a number, or a device array whose sum it adds later:
+    the array is held until it is ready (checked on each later ``inc``)
+    or until the counter is read, so counting never waits on the device."""
 
     kind = "counter"
 
     def __init__(self, name: str, help: str = ""):
         self.name, self.help = name, help
         self._vals: dict[tuple, float] = {}
+        self._pending: list[tuple] = []  # (label key, device array)
 
-    def inc(self, value: float = 1, **labels) -> None:
+    def inc(self, value=1, **labels) -> None:
         if not _ENABLED:
             return
         key = _label_key(labels)
         with _LOCK:
-            self._vals[key] = self._vals.get(key, 0) + value
+            if hasattr(value, "is_ready"):
+                self._pending.append((key, value))
+                self._settle(wait=False)
+            else:
+                self._vals[key] = self._vals.get(key, 0) + value
+
+    def _settle(self, wait: bool) -> None:
+        """Add the sums of the held arrays that are ready (every one with
+        ``wait``, as a read needs).  Called under ``_LOCK``."""
+        held = []
+        for key, arr in self._pending:
+            if wait or arr.is_ready():
+                self._vals[key] = (self._vals.get(key, 0)
+                                   + np.asarray(arr).sum().item())
+            else:
+                held.append((key, arr))
+        self._pending = held
 
     def series(self) -> list[tuple[dict, float]]:
         with _LOCK:
+            self._settle(wait=True)
             return [(dict(k), v) for k, v in sorted(self._vals.items())]
 
     def total(self, **match) -> float:
         """Sum over every label set containing all of ``match``."""
         m = {k: str(v) for k, v in match.items()}
         with _LOCK:
+            self._settle(wait=True)
             return sum(
                 v for k, v in self._vals.items()
                 if all(dict(k).get(mk) == mv for mk, mv in m.items())
@@ -131,6 +160,7 @@ class Counter:
 
     def _reset(self) -> None:
         self._vals.clear()
+        self._pending.clear()
 
 
 class Gauge(Counter):
@@ -236,73 +266,12 @@ REGISTRY = Registry()
 
 
 # ---------------------------------------------------------------------------
-# trace ring (Chrome trace_event format, Perfetto-loadable)
+# instrument entry points (registry writes: one branch while disabled)
 # ---------------------------------------------------------------------------
 
-class _TraceRing:
-    def __init__(self, cap: int = 8192):
-        self.cap = int(cap)
-        self._buf: list[dict] = []
-        self._pos = 0
-        self.dropped = 0
-
-    def append(self, ev: dict) -> None:
-        with _LOCK:
-            if len(self._buf) < self.cap:
-                self._buf.append(ev)
-            else:  # overwrite the oldest: memory stays flat under load
-                self._buf[self._pos] = ev
-                self._pos = (self._pos + 1) % self.cap
-                self.dropped += 1
-
-    def events(self) -> list[dict]:
-        with _LOCK:
-            return self._buf[self._pos:] + self._buf[: self._pos]
-
-    def clear(self) -> None:
-        with _LOCK:
-            self._buf.clear()
-            self._pos = 0
-            self.dropped = 0
-
-
-_TRACE = _TraceRing()
-
-
-def set_trace_cap(cap: int) -> None:
-    """Resize the trace ring (drops buffered events)."""
-    global _TRACE
-    with _LOCK:
-        _TRACE = _TraceRing(cap)
-
-
-def trace_events() -> list[dict]:
-    return _TRACE.events()
-
-
-def _now_us() -> float:
-    return (time.perf_counter() - _T0) * 1e6
-
-
-def now_us() -> float:
-    """Current trace-clock timestamp (µs since import) — pass as
-    ``emit_span(..., ts_us=...)`` to lay synthesized stages end to end."""
-    return _now_us()
-
-
-def _trace_event(name: str, ts_us: float, dur_us: float, args: dict) -> None:
-    _TRACE.append({
-        "name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
-        "pid": os.getpid(), "tid": threading.get_ident(),
-        "args": args,
-    })
-
-
-# ---------------------------------------------------------------------------
-# instrument entry points (all no-ops behind one branch while disabled)
-# ---------------------------------------------------------------------------
-
-def count(name: str, value: float = 1, help: str = "", **labels) -> None:
+def count(name: str, value=1, help: str = "", **labels) -> None:
+    """Add ``value`` to ``name{**labels}``: a number, or a device array
+    whose sum is added once it is ready (``Counter.inc``)."""
     if not _ENABLED:
         return
     REGISTRY.counter(name, help).inc(value, **labels)
@@ -320,77 +289,58 @@ def observe(name: str, value: float, help: str = "", **labels) -> None:
     REGISTRY.histogram(name, help).observe(value, **labels)
 
 
-class _NullSpan:
-    """The disabled path: one shared object, no per-call allocation."""
+class span:
+    """Time a stage: ``with telemetry.span("dispatch", engine="nsw"): ...``.
 
-    def __enter__(self):
-        return self
+    Opens a ``jax.profiler.TraceAnnotation(name, **labels)`` whatever the
+    switch says, so a running profiler records the span on its own clock
+    (the annotation is made only while the profiler collects: that is
+    when it records, so the off path pays one check); with telemetry
+    enabled the wall time also goes into ``stage_seconds{stage=name,
+    **labels}``.  On exception the span still closes, its annotation
+    flagged ``error=True``.  A plain class, not a generator: this sits on
+    the per-query serving path."""
 
-    def __exit__(self, *exc):
-        return False
+    __slots__ = ("name", "labels", "_t0", "_ann")
 
-
-_NULL_SPAN = _NullSpan()
-
-
-class _LiveSpan:
-    """Plain-class context manager (no generator machinery: this sits on
-    the per-query serving path, where the <5% overhead budget lives)."""
-
-    __slots__ = ("name", "labels", "t0", "ts")
-
-    def __init__(self, name: str, labels: dict):
+    def __init__(self, name: str, **labels):
         self.name, self.labels = name, labels
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
-        self.ts = (self.t0 - _T0) * 1e6
+        self._ann = None
+        if _collecting():
+            self._ann = _Annotation(self.name, **self.labels)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter() if _ENABLED else None
         return self
 
     def __exit__(self, etype, exc, tb):
-        # __exit__ IS the close-on-exception guarantee: the histogram
-        # observation and the trace event land either way
-        dur = time.perf_counter() - self.t0
-        args = dict(self.labels)
-        if etype is not None:
-            args["error"] = True
-        observe("stage_seconds", dur, stage=self.name, **self.labels)
-        _trace_event(self.name, self.ts, dur * 1e6, args)
+        if self._ann is not None:
+            if etype is not None:
+                self._ann.set_metadata(error=True)
+            self._ann.__exit__(etype, exc, tb)
+        if self._t0 is not None:
+            observe("stage_seconds", time.perf_counter() - self._t0,
+                    stage=self.name, **self.labels)
         return False
 
 
-def span(name: str, **labels):
-    """Time a stage: ``with telemetry.span("dispatch", engine="nsw"): ...``.
-
-    Records the wall time into ``stage_seconds{stage=name, **labels}`` and
-    appends one complete ('X') trace event; on exception the span still
-    closes, with ``error: true`` in the event args."""
-    if not _ENABLED:
-        return _NULL_SPAN
-    return _LiveSpan(name, labels)
-
-
-def emit_span(name: str, dur_s: float, *, ts_us: Optional[float] = None,
-              args: Optional[dict] = None, **labels) -> None:
-    """Record an externally-timed stage (same sinks as ``span``).
-
-    The jitted traversal stages are one fused dispatch — their comparison
-    counters exit as extra scalar outputs, and the caller apportions the
-    dispatch wall time across them (flagged ``estimated`` in the event
-    args by the caller); this is how those stages get flamegraph rows
-    without host callbacks inside compiled code."""
-    if not _ENABLED:
-        return
-    observe("stage_seconds", dur_s, stage=name, **labels)
-    ev_args = dict(labels)
-    if args:
-        ev_args.update(args)
-    ts = ts_us if ts_us is not None else _now_us() - dur_s * 1e6
-    _trace_event(name, ts, dur_s * 1e6, ev_args)
+def stage_scope(name: str):
+    """Decorator for a function traced under ``jax.jit``: its operations
+    carry ``name`` in their HLO metadata (``jax.named_scope``, entered
+    afresh on every trace), the device half of the host span of that
+    name.  Fixed at trace time: it adds nothing to the compile key."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def traced(*a, **kw):
+            with jax.named_scope(name):
+                return fn(*a, **kw)
+        return traced
+    return deco
 
 
 # ---------------------------------------------------------------------------
-# read-side: series access, snapshot tree, Prometheus text, trace dump
+# read-side: series access, snapshot tree, Prometheus text
 # ---------------------------------------------------------------------------
 
 def counter_series(name: str) -> list[tuple[dict, float]]:
@@ -425,8 +375,6 @@ def snapshot() -> dict:
             out["counters"][name] = {
                 _label_str(_label_key(lbl)): v for lbl, v in m.series()
             }
-    out["trace"] = {"events": len(_TRACE.events()),
-                    "dropped": _TRACE.dropped, "cap": _TRACE.cap}
     return out
 
 
@@ -443,7 +391,7 @@ def summary() -> dict:
             for lbl, rec in series.items()
         }
     return {"counters": snap["counters"], "gauges": snap["gauges"],
-            "histograms": hists, "trace": snap["trace"]}
+            "histograms": hists}
 
 
 def _esc(v: str) -> str:
@@ -493,27 +441,9 @@ def metrics_text() -> str:
     return "\n".join(lines) + "\n"
 
 
-def dump_trace(path: str) -> str:
-    """Write the trace ring as Chrome ``trace_event`` JSON — open it in
-    Perfetto (ui.perfetto.dev) or chrome://tracing for the flamegraph."""
-    payload = {
-        "traceEvents": _TRACE.events(),
-        "displayTimeUnit": "ms",
-        "metadata": {"dropped_events": _TRACE.dropped,
-                     "ring_capacity": _TRACE.cap},
-    }
-    d = os.path.dirname(path)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=1)
-    return path
-
-
 def reset() -> None:
-    """Zero every metric and clear the trace ring (tests / bench cells)."""
+    """Zero every metric (tests / bench cells)."""
     REGISTRY.reset()
-    _TRACE.clear()
 
 
 def q_label(q) -> str:

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metrics as metrics_lib
+from repro.core import telemetry as telem
 
 INF = jnp.inf
 
@@ -212,6 +213,7 @@ def _make_dist(X: Optional[jax.Array], metric: str):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("metric", "depth"))
+@telem.stage_scope("traversal")
 def _descend_impl(tree_arrays, X, queries, metric: str, depth: int):
     vantage, mu, left, right = tree_arrays
     dist = _make_dist(X, metric)
@@ -263,6 +265,7 @@ def descend_infty(
 @functools.partial(
     jax.jit, static_argnames=("metric", "q", "k", "stack_cap")
 )
+@telem.stage_scope("traversal")
 def _best_first_impl(
     tree_arrays, X, queries, max_comparisons, metric: str, q: float, k: int,
     stack_cap: int, valid=None,
@@ -639,6 +642,7 @@ def beam_plan(
     jax.jit,
     static_argnames=("metric", "q", "k", "beam_width", "bucket_cap", "depth"),
 )
+@telem.stage_scope("traversal")
 def _beam_impl(
     flat_arrays, X, queries, metric: str, q: float, k: int, beam_width: int,
     bucket_cap: int, depth: int, valid=None, codes=None, scales=None,

@@ -46,10 +46,28 @@ Telemetry (when ``core/telemetry`` is enabled): ``queue_depth``,
 ``batch_fill``, ``queue_wait_seconds``, ``admission_total{outcome=}``,
 ``shed_total{reason=}``, ``batches_formed_total``, ``breaker_state``,
 ``breaker_trips_total``.
+
+Spans (``telemetry.span``: a ``jax.profiler.TraceAnnotation`` always, so
+a running profiler puts them on the device's clock; with telemetry on,
+``stage_seconds`` too): the batcher thread is in ``wait`` while no bucket
+is flush-ready, in ``batch`` (label ``size``) for each formed batch, and
+in ``resolve`` while it hands answers back (client done-callbacks run
+there); ``SearchServer.query`` adds ``pad``/``dispatch``/``fetch`` inside
+``batch``, and the engines their stages.  Between ``start`` and ``stop``
+a ``gc.callbacks`` hook wraps each collector pause, on any thread, in a
+``gc`` span.  Nothing here waits on the device.
+
+Two counters in ``counters`` cover the runtime's whole life, at one
+``time.monotonic()`` per event: ``gc_max_ms``, the longest collector
+pause, and ``dispatch_gap_max_ms``, the longest interval from the end of
+one ``server.query`` to the start of the next among those that begin
+with requests in the queue (host time the device spent waiting on the
+runtime, not on traffic).
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import itertools
 import json
 import threading
@@ -243,7 +261,11 @@ class ServingRuntime:
             "admitted": 0, "rejected_capacity": 0, "rejected_breaker": 0,
             "completed": 0, "shed_expired": 0, "shed_breaker": 0,
             "shed_shutdown": 0, "dispatch_faults": 0, "batches": 0,
+            "gc_max_ms": 0.0, "dispatch_gap_max_ms": 0.0,
         }
+        self._gc_span = None  # the open ``gc`` span and its start
+        self._gc_t0 = 0.0
+        self._gap_from: Optional[float] = None  # last query end, queue busy
         if telem.enabled():
             telem.REGISTRY.histogram(
                 "batch_fill", "requests per formed batch",
@@ -257,6 +279,7 @@ class ServingRuntime:
             self._running = True
             self._thread = threading.Thread(
                 target=self._batcher, name="serving-batcher", daemon=True)
+            gc.callbacks.append(self._on_gc)
             self._thread.start()
         return self
 
@@ -264,6 +287,8 @@ class ServingRuntime:
         with self._lock:
             self._running = False
             t, self._thread = self._thread, None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         if t is not None:
             t.join(timeout=10.0)
         for r in self.queue.drain():
@@ -305,14 +330,17 @@ class ServingRuntime:
             with self._lock:
                 if not self._running:
                     return
-            got = self.queue.take_batch(self.policy.max_batch,
-                                        self.policy.flush_ms / 1e3)
+            with telem.span("wait"):
+                got = self.queue.take_batch(self.policy.max_batch,
+                                            self.policy.flush_ms / 1e3)
             if got is None:
+                self._gap_from = None  # the queue ran dry
                 continue
             key, reqs = got
             self._gauge_depth()
             try:
-                self._dispatch(key, reqs)
+                with telem.span("batch", size=len(reqs)):
+                    self._dispatch(key, reqs)
             except BaseException as e:  # never kill the batcher silently
                 for r in reqs:
                     if not r.future.done():
@@ -356,9 +384,14 @@ class ServingRuntime:
                 # dispatch thread (queue grows, deadlines slip), fault
                 # rules raise — both feed the breaker deterministically
                 self.server.chaos.on_slow_search()
-            res = self.server.query(batch, k=k, budget=eff_budget,
-                                    filter=live[0].filter,
-                                    deadline_ms=batch_dl)
+            self._note_gap()
+            try:
+                res = self.server.query(batch, k=k, budget=eff_budget,
+                                        filter=live[0].filter,
+                                        deadline_ms=batch_dl)
+            finally:
+                self._gap_from = (time.monotonic() if self.queue.depth()
+                                  else None)
         except Exception as e:
             ok = False
             self._count("dispatch_faults")
@@ -369,19 +402,20 @@ class ServingRuntime:
         else:
             done = time.monotonic()
             n_met = 0
-            for i, r in enumerate(live):
-                met = r.dl_abs is None or done <= r.dl_abs
-                n_met += met
-                queue_ms = (t0 - r.t_submit) * 1e3
-                r.future.set_result(ServedResult(
-                    res.idx[i:i + 1], res.dist[i:i + 1],
-                    res.comparisons[i:i + 1], degraded=res.degraded,
-                    shards_answered=res.shards_answered,
-                    shards_total=res.shards_total, retries=res.retries,
-                    deadline_met=met, queue_ms=queue_ms, outcome="ok"))
-                self._count("completed")
-                telem.count("admission_total", outcome="completed")
-                telem.observe("queue_wait_seconds", queue_ms / 1e3)
+            with telem.span("resolve"):
+                for i, r in enumerate(live):
+                    met = r.dl_abs is None or done <= r.dl_abs
+                    n_met += met
+                    queue_ms = (t0 - r.t_submit) * 1e3
+                    r.future.set_result(ServedResult(
+                        res.idx[i:i + 1], res.dist[i:i + 1],
+                        res.comparisons[i:i + 1], degraded=res.degraded,
+                        shards_answered=res.shards_answered,
+                        shards_total=res.shards_total, retries=res.retries,
+                        deadline_met=met, queue_ms=queue_ms, outcome="ok"))
+                    self._count("completed")
+                    telem.count("admission_total", outcome="completed")
+                    telem.observe("queue_wait_seconds", queue_ms / 1e3)
             # a whole-batch deadline miss counts as a dispatch failure:
             # N consecutive ones mean the engine can't keep up — trip
             ok = n_met == len(live)
@@ -426,6 +460,32 @@ class ServingRuntime:
     def _count(self, key: str, n: int = 1) -> None:
         with self._lock:
             self.counters[key] += n
+
+    def _note_max(self, key: str, ms: float) -> None:
+        # no lock: the gc hook can fire while this thread holds it.  Each
+        # key has one writer at a time (the batcher; the collector)
+        if ms > self.counters[key]:
+            self.counters[key] = ms
+
+    def _note_gap(self) -> None:
+        """A ``server.query`` starts: close the dispatch gap open since
+        the last one ended with requests in the queue."""
+        if self._gap_from is not None:
+            self._note_max("dispatch_gap_max_ms",
+                           (time.monotonic() - self._gap_from) * 1e3)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: a ``gc`` span around each collector
+        pause, and the longest pause in ``counters["gc_max_ms"]``."""
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+            self._gc_span = telem.span("gc", generation=info["generation"])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+            self._note_max("gc_max_ms",
+                           (time.monotonic() - self._gc_t0) * 1e3)
 
     def _gauge_depth(self) -> None:
         telem.set_gauge("queue_depth", self.queue.depth(),

@@ -585,11 +585,13 @@ class SearchServer:
                 # — the headroom the degradation ladder keys off
                 telem.set_gauge("deadline_slack_frac", dl.fraction_left(),
                                 engine=self.engine)
-        res = ServedResult(
-            np.asarray(idx)[:B], np.asarray(dist)[:B], np.asarray(comps)[:B],
-            degraded=degraded, shards_answered=S - len(excluded),
-            shards_total=S, retries=retries, deadline_met=deadline_met,
-        )
+        with telem.span("fetch", engine=self.engine, bucket=Bp):
+            res = ServedResult(
+                np.asarray(idx)[:B], np.asarray(dist)[:B],
+                np.asarray(comps)[:B], degraded=degraded,
+                shards_answered=S - len(excluded), shards_total=S,
+                retries=retries, deadline_met=deadline_met,
+            )
         if record and self._probe is not None:
             # observe-only: the answer and its recorded latency are final
             # before the probe sees anything (DESIGN.md §17)
@@ -931,11 +933,6 @@ class SearchServer:
         exposition format — what ``examples/serve_search.py
         --metrics-port`` serves at ``/metrics`` (DESIGN.md §16)."""
         return telem.metrics_text()
-
-    def dump_trace(self, path: str) -> str:
-        """Write the telemetry trace ring as Chrome/Perfetto
-        ``trace_event`` JSON; returns ``path``."""
-        return telem.dump_trace(path)
 
     def serve(self, batches, k: int = 10, *, budget: Optional[int] = None,
               filter: Optional[dict] = None,

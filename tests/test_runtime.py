@@ -9,6 +9,7 @@ fix), the multi-process HTTP socket path, and the open-loop overload
 acceptance run (≥2× measured saturation: bounded p99 for admitted work,
 explicit outcomes for everything else, recall of admitted answers held).
 """
+import gc
 import json
 import subprocess
 import sys
@@ -209,6 +210,44 @@ def test_open_breaker_sheds_queued_work(corpus):
         assert run.stats()["shed_breaker"] == 1
     finally:
         run.stop()
+
+
+# ------------------------------------- stall counters over the whole window
+
+def _collect_garbage(run, corpus):
+    """A full collection while the runtime runs, over some cyclic junk."""
+    junk = [[] for _ in range(20_000)]
+    for j in junk:
+        j.append(j)
+    del junk
+    gc.collect()
+
+
+def _drain_a_full_queue(run, corpus):
+    """Three batches queued at once: the second and third dispatch start
+    with requests waiting, after the chaos stall in front of each."""
+    for t in [run.submit(corpus[i], k=5) for i in range(12)]:
+        t.result(timeout=30)
+
+
+@pytest.mark.parametrize("counter, rules, act, floor_ms", [
+    ("gc_max_ms", [], _collect_garbage, 0.0),
+    ("dispatch_gap_max_ms",
+     [{"site": "slow_search", "kind": "latency", "rate": 1.0, "ms": 50}],
+     _drain_a_full_queue, 49.9),
+])
+def test_stall_counters_catch_a_stall(corpus, counter, rules, act, floor_ms):
+    srv = _chaos_server(corpus, rules)
+    srv.query(corpus[:4], k=5)  # warm the bucket: no compile in the gaps
+    run = ServingRuntime(srv, OverloadPolicy(max_batch=4, flush_ms=1.0))
+    assert run.counters[counter] == 0.0
+    run.start()
+    try:
+        act(run, corpus)
+    finally:
+        run.stop()
+    assert run.counters[counter] > floor_ms
+    assert gc.callbacks.count(run._on_gc) == 0  # the hook left with stop
 
 
 # --------------------------------------- SearchServer counters under threads

@@ -1,30 +1,36 @@
-"""Search telemetry subsystem (DESIGN.md §16): registry, spans, trace ring,
-Prometheus exposition, and the instrumented query path.
+"""Search telemetry subsystem (DESIGN.md §16): registry, spans on the
+profiler's clock, Prometheus exposition, and the instrumented query path.
 
 Pins the PR's acceptance invariants:
   * a beam query's per-stage comparison counters (traversal /
     centroid_rank / bucket_scan, threaded out of the jitted program as
     extra scalar outputs) sum exactly to the engine-reported comparisons,
     with the rerank stage on top at the engine level;
-  * the trace of one instrumented beam query holds >= 4 distinct stage
-    spans;
+  * a span lands in a profiler trace on the ``/host:CPU`` plane, with its
+    labels as stats, and an instrumented beam query shows its stages
+    there; the jitted stages carry their names into the HLO metadata;
   * ``metrics_text()`` parses as Prometheus text exposition (cumulative
     ``_bucket{le=...}`` histograms + ``_sum``/``_count``);
-  * enabling telemetry changes NO search result ids (bit-exact);
+  * enabling telemetry changes NO search result ids (bit-exact), and the
+    search never waits on the device for it;
   * under injected faults the counters stay consistent — telemetry
     retries == the server's fault_counters == the chaos plan's injected
     count — and spans close (flagged) on exception paths;
-  * the trace ring is bounded and never corrupts under overflow;
+  * a warm bucket compiles nothing with the spans in place;
   * ``SearchServer``'s latency record is a bounded ring: 100k appends
     hold memory flat while percentile semantics cover the window.
 """
+import glob
 import json
 import math
+import os
 import re
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import baselines
 from repro.core import chaos as chaos_lib
 from repro.core import index as index_lib
 from repro.core import telemetry as telem
@@ -40,11 +46,9 @@ def _clean_registry():
     disabled + zeroed so no counters leak across the suite."""
     telem.disable()
     telem.reset()
-    telem.set_trace_cap(8192)
     yield
     telem.disable()
     telem.reset()
-    telem.set_trace_cap(8192)
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +68,29 @@ def infinity_engine(data):
     })
 
 
+def _host_spans(tmp_path, body) -> list:
+    """Run ``body`` under the JAX profiler; the host-plane events it
+    recorded, as ``(name, {stat: value})`` in start order."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    evs = [ev for plane in pd.planes if plane.name == "/host:CPU"
+           for line in plane.lines for ev in line.events]
+    evs.sort(key=lambda ev: ev.start_ns)
+    return [(ev.name, {k: str(v) for k, v in ev.stats}) for ev in evs]
+
+
+def _named(spans, name) -> list:
+    return [stats for n, stats in spans if n == name]
+
+
 # ---------------------------------------------------------------------------
 # registry primitives
 # ---------------------------------------------------------------------------
@@ -76,7 +103,7 @@ def test_disabled_entry_points_are_noops():
         pass
     snap = telem.snapshot()
     assert snap["counters"] == {} and snap["histograms"] == {}
-    assert telem.trace_events() == []
+    assert snap["gauges"] == {}
 
 
 def test_counter_accumulates_per_label_set():
@@ -107,51 +134,44 @@ def test_histogram_buckets_are_fixed_and_cumulative_in_exposition():
     assert rec["buckets"][-1] == 1  # the +Inf overflow slot
 
 
-def test_span_records_histogram_and_trace_event():
+def test_counter_adds_a_device_array_when_read():
     telem.enable()
-    with telem.span("stage_y", engine="e", q="inf"):
-        pass
+    comps = jax.numpy.arange(4, dtype=jax.numpy.int32)  # sums to 6
+    telem.count("c_total", comps, engine="a", stage="s1")
+    telem.count("c_total", 2, engine="a", stage="s1")
+    assert telem.counter_total("c_total", engine="a", stage="s1") == 8
+    assert telem.counter_series("c_total") == [
+        ({"engine": "a", "stage": "s1"}, 8)]
+
+
+def test_span_records_histogram_and_trace_event(tmp_path):
+    telem.enable()
+
+    def body():
+        with telem.span("stage_y", engine="e", q="inf"):
+            pass
+
+    spans = _host_spans(tmp_path, body)
     [(lbl, rec)] = telem.histogram_series("stage_seconds")
     assert lbl == {"engine": "e", "q": "inf", "stage": "stage_y"}
     assert rec["count"] == 1
-    [ev] = telem.trace_events()
-    assert ev["ph"] == "X" and ev["name"] == "stage_y"
-    assert ev["dur"] >= 0 and "error" not in ev["args"]
+    [stats] = _named(spans, "stage_y")
+    assert stats == {"engine": "e", "q": "inf"}  # no error flag
 
 
-def test_span_closes_on_exception_and_flags_error():
+def test_span_closes_on_exception_and_flags_error(tmp_path):
     telem.enable()
-    with pytest.raises(RuntimeError):
-        with telem.span("doomed", engine="e"):
-            raise RuntimeError("boom")
+
+    def body():
+        with pytest.raises(RuntimeError):
+            with telem.span("doomed", engine="e"):
+                raise RuntimeError("boom")
+
+    spans = _host_spans(tmp_path, body)
     [(lbl, rec)] = telem.histogram_series("stage_seconds")
     assert rec["count"] == 1  # observed despite the raise
-    [ev] = telem.trace_events()
-    assert ev["name"] == "doomed" and ev["args"]["error"] is True
-
-
-def test_trace_ring_bounded_and_uncorrupted_under_overflow():
-    telem.enable()
-    telem.set_trace_cap(16)
-    for i in range(100):
-        telem.emit_span(f"s{i}", 1e-4, engine="e")
-    evs = telem.trace_events()
-    assert len(evs) == 16
-    # oldest-overwritten: the survivors are the most recent 16, in order
-    assert [e["name"] for e in evs] == [f"s{i}" for i in range(84, 100)]
-    assert all(e["ph"] == "X" and "ts" in e and "dur" in e for e in evs)
-    assert telem.snapshot()["trace"]["dropped"] == 84
-
-
-def test_dump_trace_is_perfetto_loadable_json(tmp_path):
-    telem.enable()
-    with telem.span("a", engine="e"):
-        pass
-    out = telem.dump_trace(str(tmp_path / "trace.json"))
-    doc = json.load(open(out))
-    assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
-    ev = doc["traceEvents"][0]
-    assert {"name", "ph", "ts", "dur", "pid", "tid", "args"} <= set(ev)
+    [stats] = _named(spans, "doomed")
+    assert stats["engine"] == "e" and stats["error"] == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -241,16 +261,24 @@ def test_beam_default_return_signature_unchanged(infinity_engine):
     assert len(out) == 3  # (idx, dist, comps) — pre-PR callers unaffected
 
 
-def test_engine_counters_sum_to_reported_comparisons(infinity_engine, data):
+def test_engine_counters_sum_to_reported_comparisons(infinity_engine, data,
+                                                    tmp_path):
     _, Q = data
     telem.enable()
-    res = infinity_engine.search(Q, k=5, mode="beam")
-    reported = int(np.asarray(res.comparisons).sum())
+    out = {}
+
+    def body():
+        out["res"] = infinity_engine.search(Q, k=5, mode="beam")
+        jax.block_until_ready(out["res"].idx)
+
+    spans = _host_spans(tmp_path, body)
+    reported = int(np.asarray(out["res"].comparisons).sum())
     counted = telem.counter_total("comparisons_total", engine="infinity")
     assert counted == reported
-    # the trace of one beam query holds >= 4 distinct stage spans
-    names = {e["name"] for e in telem.trace_events()}
-    assert {"traversal", "centroid_rank", "bucket_scan", "rerank"} <= names
+    # the profiler's trace of one beam query holds its three host stages
+    names = [n for n, _ in spans if n in ("embed", "traversal", "rerank")]
+    assert names == ["embed", "traversal", "rerank"]
+    assert _named(spans, "traversal")[0]["mode"] == "beam"
 
 
 def test_enabling_telemetry_is_bit_exact(infinity_engine, data):
@@ -265,11 +293,86 @@ def test_enabling_telemetry_is_bit_exact(infinity_engine, data):
             np.asarray(off.comparisons), np.asarray(on.comparisons))
 
 
+@pytest.mark.parametrize("mode", ["beam", "best_first", "descend"])
+def test_search_never_waits_on_the_device(infinity_engine, data, monkeypatch,
+                                          mode):
+    """Telemetry on, the engine records its stages without one
+    ``block_until_ready``: counters take the device arrays as they are."""
+    _, Q = data
+    telem.enable()
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waits.append(1) or real(x))
+    res = infinity_engine.search(Q, k=5, mode=mode)
+    assert waits == []
+    assert telem.counter_total("comparisons_total", engine="infinity") == \
+        int(np.asarray(res.comparisons).sum())
+
+
+def _lowered(kind: str, eng) -> str:
+    """HLO text with locations of one jitted stage program."""
+    Zq = eng.Z[:4]
+    t = eng.tree
+    tree = (t.vantage, t.mu, t.left, t.right)
+    if kind == "_descend_impl":
+        low = vptree_lib._descend_impl.lower(tree, eng.Z, Zq,
+                                             metric="euclidean", depth=t.depth)
+    elif kind == "_best_first_impl":
+        low = vptree_lib._best_first_impl.lower(
+            tree, eng.Z, Zq, jax.numpy.int32(64), metric="euclidean",
+            q=math.inf, k=3, stack_cap=2 * t.depth + 8)
+    elif kind == "_beam_impl":
+        flat, Zf, _ = eng._flat_view()
+        low = vptree_lib._beam_impl.lower(
+            (flat.mu, flat.child_in, flat.child_out, flat.rad_in,
+             flat.rad_out, flat.bucket_rows, flat.perm, flat.centroids),
+            Zf, Zq, metric="euclidean", q=math.inf, k=3, beam_width=4,
+            bucket_cap=2, depth=flat.depth)
+    else:
+        low = baselines.brute_force.lower(eng.X, eng.X[:4], k=3)
+    return low.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("program, stage", [
+    ("_descend_impl", "traversal"), ("_best_first_impl", "traversal"),
+    ("_beam_impl", "traversal"), ("brute_force", "scan")])
+def test_stage_names_reach_the_hlo_metadata(infinity_engine, program, stage):
+    """The jitted stages trace under ``jax.named_scope(stage)``: the name
+    sits in their ops' metadata, where the profiler's op events read it."""
+    txt = _lowered(program, infinity_engine)
+    assert f'"jit({program})/{stage}/' in txt
+
+
+@pytest.mark.parametrize("engine", ["brute", "infinity"])
+def test_warm_bucket_compiles_nothing_with_spans(data, engine):
+    X, Q = data
+    cfg = {} if engine == "brute" else {
+        "q": math.inf, "train_steps": 20, "proj_sample": 64,
+        "budget": 192, "rerank": 32}
+    telem.enable()
+    srv = SearchServer(X, engine=engine, cfg=cfg)
+    srv.query(Q, k=5)  # compiles this bucket
+    compiles = []
+
+    def on_event(event: str, duration: float, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        with telem.span("caller", engine=engine):
+            srv.query(Q, k=5)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+
+
 # ---------------------------------------------------------------------------
 # instrumented serving path under failure (chaos consistency)
 # ---------------------------------------------------------------------------
 
-def test_server_counters_match_fault_counters_and_chaos(data):
+def test_server_counters_match_fault_counters_and_chaos(data, tmp_path):
     X, Q = data
     telem.enable()
     plan = chaos_lib.FaultPlan(
@@ -277,9 +380,13 @@ def test_server_counters_match_fault_counters_and_chaos(data):
     srv = SearchServer(X, engine="brute", cfg={}, chaos=plan,
                        policy=FaultPolicy(max_retries=3,
                                           backoff_base_s=0.001))
-    srv.query(Q, k=5)
-    srv.query(Q, k=5)  # absorbs injection 1
-    srv.query(Q, k=5)  # absorbs injection 2
+
+    def body():
+        srv.query(Q, k=5)
+        srv.query(Q, k=5)  # absorbs injection 1
+        srv.query(Q, k=5)  # absorbs injection 2
+
+    spans = _host_spans(tmp_path, body)
     injected = sum(plan.stats()["injected"].values())
     assert injected == 2
     assert srv.fault_counters["retries"] == injected
@@ -287,12 +394,12 @@ def test_server_counters_match_fault_counters_and_chaos(data):
     assert telem.counter_total("faults_total", engine="brute") == injected
     assert telem.counter_total("queries_total", engine="brute") == 3 * len(Q)
     # every retried dispatch opened AND closed a span: 3 clean + 2 flagged
-    dispatch = [e for e in telem.trace_events() if e["name"] == "dispatch"]
+    dispatch = _named(spans, "dispatch")
     assert len(dispatch) == 5
-    assert sum(bool(e["args"].get("error")) for e in dispatch) == 2
+    assert sum("error" in st for st in dispatch) == 2
 
 
-def test_fault_storm_closes_spans_on_the_raising_path(data):
+def test_fault_storm_closes_spans_on_the_raising_path(data, tmp_path):
     X, Q = data
     telem.enable()
     plan = chaos_lib.FaultPlan(
@@ -300,17 +407,21 @@ def test_fault_storm_closes_spans_on_the_raising_path(data):
     srv = SearchServer(X, engine="brute", cfg={}, chaos=plan,
                        policy=FaultPolicy(max_retries=1,
                                           backoff_base_s=0.001))
-    srv.query(Q, k=5)
-    with pytest.raises(chaos_lib.TransientFault):
+
+    def body():
         srv.query(Q, k=5)
-    dispatch = [e for e in telem.trace_events() if e["name"] == "dispatch"]
+        with pytest.raises(chaos_lib.TransientFault):
+            srv.query(Q, k=5)
+        with telem.span("after"):  # the span machinery still works
+            pass
+
+    spans = _host_spans(tmp_path, body)
+    dispatch = _named(spans, "dispatch")
     # 1 clean + 2 flagged (first attempt + the exhausted retry): no span
     # leaks open even though the second query raised out of the server
     assert len(dispatch) == 3
-    assert sum(bool(e["args"].get("error")) for e in dispatch) == 2
-    # the trace ring stays well-formed after the exception path
-    assert all(e["ph"] == "X" and e["dur"] >= 0
-               for e in telem.trace_events())
+    assert sum("error" in st for st in dispatch) == 2
+    assert len(_named(spans, "after")) == 1
 
 
 def test_deadline_miss_counted_consistently(data):
