@@ -39,6 +39,7 @@ from repro.core import scan as scan_lib
 from repro.core import telemetry as telem
 from repro.core import vptree as vptree_lib
 from repro.core.index import SearchResult
+from repro.kernels import bestfirst
 
 
 def _note_comps(engine: str, stage: str, qv: float, comps) -> None:
@@ -105,6 +106,10 @@ class InfinityIndex:
     #: lazily-built beam state: {"flat": FlatVPTree, "Zf": Z[perm],
     #: "zcodes": (int8 codes of Zf, scales) once a quant store is attached}
     _flat: Optional[dict] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
+    #: lazily-built layout of (tree, Z) for the best-first kernel on a TPU
+    _kview: Optional[bestfirst.View] = dataclasses.field(
         default=None, repr=False, compare=False
     )
 
@@ -326,6 +331,7 @@ class InfinityIndex:
                     self.tree, Zq, q=self.config.q, k=K, X=self.Z,
                     metric="euclidean",
                     max_comparisons=max_comparisons, valid=mask,
+                    kernel_view=self._kernel_view(),
                 )
             _note_comps("infinity", "traversal", self.config.q, comps)
         if rerank and K > k:
@@ -386,6 +392,15 @@ class InfinityIndex:
         zc = cache["zcodes"] if getattr(self, "quant", None) is not None else None
         return cache["flat"], cache["Zf"], zc
 
+    def _kernel_view(self) -> Optional[bestfirst.View]:
+        """The best-first kernel's layout of (tree, Z), built on first use
+        where the kernel runs, else None; ``refresh`` resets it."""
+        if self._kview is None and bestfirst.applies(self.Z, "euclidean", None):
+            object.__setattr__(self, "_kview", bestfirst.view(
+                (self.tree.vantage, self.tree.mu, self.tree.left,
+                 self.tree.right), self.Z))
+        return self._kview
+
     def _rerank(self, Q: jax.Array, idx: jax.Array, k: int):
         """Specific search (F.5): original-metric distances to K candidates,
         keep the best k — per-query candidate scoring + selection routed
@@ -412,6 +427,8 @@ class InfinityIndex:
             (self.X, self.Z, self.phi_params,
              (self.tree.vantage, self.tree.mu, self.tree.left, self.tree.right))
         ) + index_lib.side_store_bytes(self)
+        if self._kview is not None:
+            total += index_lib.pytree_nbytes(tuple(self._kview))
         if self._flat is not None:
             flat = self._flat["flat"]
             total += index_lib.pytree_nbytes(

@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core import metrics as metrics_lib
 from repro.core import telemetry as telem
+from repro.kernels import bestfirst
 
 INF = jnp.inf
 
@@ -262,6 +263,25 @@ def descend_infty(
 # finite-q best-first search (Algorithm 2) with comparison budget
 # ---------------------------------------------------------------------------
 
+def _topk_insert(kd: jax.Array, ki: jax.Array, d: jax.Array, j: jax.Array):
+    """Insert candidate (d, j) into the ascending (k,) buffer (kd, ki).
+
+    The candidate lands after every entry it does not beat, which is where
+    a stable sort of the k + 1 entries puts it, so ties keep their order;
+    the last entry drops off.  A +inf (or NaN) candidate lands past the end
+    and changes nothing.  Compare, select and a one-slot shift: no sort and
+    no data-dependent gather over the buffer.
+    """
+    slot = jnp.arange(kd.shape[-1])
+    pos = jnp.sum(~(d < kd))
+
+    def put(buf, x):
+        shifted = jnp.concatenate([x[None], buf[:-1]])
+        return jnp.where(slot < pos, buf, jnp.where(slot == pos, x, shifted))
+
+    return put(kd, d), put(ki, j)
+
+
 @functools.partial(
     jax.jit, static_argnames=("metric", "q", "k", "stack_cap")
 )
@@ -295,19 +315,15 @@ def _best_first_impl(
             j = vantage[node]
             d = dist(qr, j)
             comps = comps + 1
-            # top-k insert (k is small; argsort of k+1 elements); filtered-
-            # out vantages insert as (+inf, -1) — a no-op slot
+            # sorted top-k insert; filtered-out vantages insert as
+            # (+inf, -1), which falls off the end — a no-op
             if valid is None:
                 ins_d, ins_i = d, j
             else:
                 ok = valid[j]
                 ins_d = jnp.where(ok, d, INF)
                 ins_i = jnp.where(ok, j, -1)
-            cd = jnp.concatenate([kd, ins_d[None]])
-            ci = jnp.concatenate([ki, ins_i[None]])
-            order = jnp.argsort(cd)
-            kd = cd[order][:k]
-            ki = ci[order][:k]
+            kd, ki = _topk_insert(kd, ki, ins_d, ins_i)
             tau = kd[k - 1]
 
             m = mu[node]
@@ -378,6 +394,7 @@ def search_best_first(
     max_comparisons: Optional[int] = None,
     valid: Optional[jax.Array] = None,
     with_truncated: bool = False,
+    kernel_view=None,
 ):
     """Algorithm 2: best-first q-metric VP search with top-k results.
 
@@ -393,20 +410,32 @@ def search_best_first(
     stack hit its capacity (a dropped push — the default cap of
     ``2*depth+8`` never trips, since a binary DFS holds at most depth+1
     deferred nodes, but callers overriding the cap can detect it).
+    On a TPU, with vectors ``X`` under the Euclidean metric and no
+    ``valid``, the loop runs as one kernel (``kernels/bestfirst``);
+    ``kernel_view``, where given, is its ``view`` of (tree, X), kept by the
+    caller so it is not rebuilt on every call.
     """
     budget = tree.num_nodes if max_comparisons is None else max_comparisons
     cap = 2 * tree.depth + 8
-    ki, kd, comps, trunc = _best_first_impl(
-        (tree.vantage, tree.mu, tree.left, tree.right),
-        X,
-        queries,
-        jnp.asarray(budget, jnp.int32),  # traced: int AND tracer budgets work
-        metric,
-        float(q),
-        int(k),
-        int(cap),
-        None if valid is None else jnp.asarray(valid, bool),
-    )
+    arrays = (tree.vantage, tree.mu, tree.left, tree.right)
+    budget = jnp.asarray(budget, jnp.int32)  # traced: int AND tracer budgets work
+    if bestfirst.applies(X, metric, valid):
+        # on a TPU: the same loop as one kernel, not ~30 launches per step
+        ki, kd, comps, trunc = bestfirst.best_first(
+            arrays, X, queries, budget, q=float(q), k=int(k),
+            stack_cap=int(cap), tv=kernel_view)
+    else:
+        ki, kd, comps, trunc = _best_first_impl(
+            arrays,
+            X,
+            queries,
+            budget,
+            metric,
+            float(q),
+            int(k),
+            int(cap),
+            None if valid is None else jnp.asarray(valid, bool),
+        )
     if with_truncated:
         return ki, kd, comps, trunc
     return ki, kd, comps

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import scan as scan_lib
+from repro.kernels.bestfirst.bestfirst import best_first_pallas, view
 from repro.kernels.pdist.pdist import pdist_pallas
 from repro.kernels.qpath.qpath import qpath_matmul_pallas
 from repro.kernels.topk.ops import tile_config
@@ -113,6 +114,22 @@ def test_qpath_minmax_compiles(one_chip):
 
     _kernel_compiles(fn, _spec(one_chip, (2048, 2048)),
                      _spec(one_chip, (2048, 2048)))
+
+
+@pytest.mark.parametrize("q", [float("inf"), 2.0])
+def test_best_first_compiles(one_chip, q):
+    """The infinity engine's traversal: a 32-query batch over a 1M-node
+    tree of 32-d Phi rows, K 512, the budget traced."""
+    n, d, B = N, 32, 32
+    tree = [_spec(one_chip, (n,), jnp.int32), _spec(one_chip, (n,)),
+            _spec(one_chip, (n,), jnp.int32), _spec(one_chip, (n,), jnp.int32)]
+
+    def fn(tree, x, queries, budget):
+        return best_first_pallas(view(tree, x), queries, budget, q=q, k=512,
+                                 stack_cap=48, interpret=False)
+
+    _kernel_compiles(fn, tree, _spec(one_chip, (n, d)),
+                     _spec(one_chip, (B, d)), _spec(one_chip, (), jnp.int32))
 
 
 def test_jnp_topk_scan_fits_one_chip(one_chip):

@@ -1,6 +1,8 @@
 """VP trees: Algorithm 1 build, Theorem 1 descent, Algorithm 2 best-first."""
 import math
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -256,3 +258,177 @@ def test_with_truncated_flag_api_default_false():
     out4 = vptree.search_best_first(
         tree, Q, q=2.0, k=2, X=jnp.asarray(X), with_truncated=True)
     assert len(out4) == 4 and not np.asarray(out4[3]).any()
+
+
+# ---------------------------------------------------------------------------
+# sorted top-k insert of the best-first loop: same buffer as a stable sort
+# ---------------------------------------------------------------------------
+
+def _stable_insert(kd, ki, d, j):
+    """Reference: stable argsort of the k + 1 entries, keep the first k."""
+    cd = np.append(kd, np.float32(d))
+    ci = np.append(ki, np.int32(j))
+    order = np.argsort(cd, kind="stable")[: kd.shape[0]]
+    return cd[order], ci[order]
+
+
+@pytest.mark.parametrize("k", [1, 2, 10, 512])
+def test_topk_insert_matches_stable_argsort(k):
+    """Tie-heavy sorted buffers, part filled (+inf, -1), and +inf inserts:
+    the shift-insert returns the stable argsort's buffer, bit for bit."""
+    rng = np.random.default_rng(k)
+    trials = 64
+    kd = np.full((trials, k), np.inf, np.float32)
+    ki = np.full((trials, k), -1, np.int32)
+    for t in range(trials):
+        m = int(rng.integers(0, k + 1))
+        kd[t, :m] = np.sort(rng.integers(0, 4, m)).astype(np.float32)
+        ki[t, :m] = rng.integers(0, 10_000, m)
+    d = rng.integers(0, 5, trials).astype(np.float32)
+    j = rng.integers(0, 10_000, trials).astype(np.int32)
+    d[::4], j[::4] = np.inf, -1  # filtered-out candidates
+    got_d, got_i = jax.jit(jax.vmap(vptree._topk_insert))(
+        jnp.asarray(kd), jnp.asarray(ki), jnp.asarray(d), jnp.asarray(j))
+    for t in range(trials):
+        ref_d, ref_i = _stable_insert(kd[t], ki[t], d[t], j[t])
+        np.testing.assert_array_equal(np.asarray(got_d[t]), ref_d)
+        np.testing.assert_array_equal(np.asarray(got_i[t]), ref_i)
+
+
+def _np_best_first(tree, row, q, k, valid=None):
+    """Algorithm 2 in numpy, step for step as ``_best_first_impl`` (explicit
+    DFS stack, float32 prune rules), with a stable-argsort top-k buffer."""
+    vantage, mu, left, right = (np.asarray(a) for a in
+                                (tree.vantage, tree.mu, tree.left, tree.right))
+    f32 = np.float32
+    kd = np.full(k, np.inf, f32)
+    ki = np.full(k, -1, np.int32)
+    stack, comps = [0], 0
+    while stack:
+        node = stack.pop()
+        j = int(vantage[node])
+        d = f32(row[j])
+        comps += 1
+        ok = valid is None or bool(valid[j])
+        kd, ki = _stable_insert(kd, ki, d if ok else np.inf, j if ok else -1)
+        tau, m = kd[k - 1], f32(mu[node])
+        if math.isinf(q):
+            prune_out = max(d, tau) < m
+            prune_in = max(m, tau) <= d
+        else:
+            s = max(d, m, tau if np.isfinite(tau) else f32(0), f32(1e-30))
+            dq, mq = (d / s) ** f32(q), (m / s) ** f32(q)
+            tq = (tau / s) ** f32(q) if np.isfinite(tau) else f32(np.inf)
+            prune_out = dq + tq < mq
+            prune_in = mq + tq <= dq
+        push_left = left[node] >= 0 and not prune_in
+        push_right = right[node] >= 0 and not prune_out
+        if d < m:
+            pushes = [(right[node], push_right), (left[node], push_left)]
+        else:
+            pushes = [(left[node], push_left), (right[node], push_right)]
+        stack += [int(c) for c, ok_c in pushes if ok_c]
+    return ki, kd, comps
+
+
+@pytest.mark.parametrize("filtered", [False, True])
+@pytest.mark.parametrize("q", [math.inf, 2.0])
+def test_best_first_k512_matches_numpy_stable_argsort(q, filtered):
+    """k = 512 on a tie-heavy integer corpus (L1 over {0..3}^6): ids,
+    distances and comparisons equal numpy Algorithm 2 with a stable-argsort
+    buffer, with and without a ``valid`` mask."""
+    rng = np.random.default_rng(31)
+    C = rng.integers(0, 4, size=(1500, 6))
+    Qi = rng.integers(0, 4, size=(6, 6))
+    D = np.abs(C[:, None, :] - C[None, :, :]).sum(-1).astype(np.float32)
+    rows = np.abs(Qi[:, None, :] - C[None, :, :]).sum(-1).astype(np.float32)
+    tree = vptree.build_vptree(D=D, seed=5)
+    valid = rng.random(1500) < 0.6 if filtered else None
+    ki, kd, comps = vptree.search_best_first(
+        tree, jnp.asarray(rows), q=q, k=512,
+        valid=None if valid is None else jnp.asarray(valid))
+    for b in range(rows.shape[0]):
+        ref_i, ref_d, ref_c = _np_best_first(tree, rows[b], q, 512, valid)
+        np.testing.assert_array_equal(np.asarray(ki[b]), ref_i)
+        np.testing.assert_array_equal(np.asarray(kd[b]), ref_d)
+        assert int(comps[b]) == ref_c
+
+
+def test_best_first_loop_has_no_sort_or_buffer_gather():
+    """The lowered best-first program at k = 512 holds no sort and no gather
+    with k + 1 elements per query: the top-k insert is compare and select."""
+    X, _ = _data(64, seed=25)
+    tree = vptree.build_vptree(X, metric="euclidean", seed=9)
+    B, k = 4, 512
+    txt = vptree._best_first_impl.lower(
+        (tree.vantage, tree.mu, tree.left, tree.right), jnp.asarray(X),
+        jnp.asarray(X[:B]), jnp.int32(64), metric="euclidean", q=math.inf,
+        k=k, stack_cap=2 * tree.depth + 8).as_text()
+    assert "stablehlo.sort" not in txt
+    gathers = re.findall(r"stablehlo\.gather.*-> tensor<([0-9x]+)x[a-z0-9]+>",
+                         txt)
+    assert gathers, "no gather found: the lowered text changed form"
+    for shape in gathers:
+        dims = [int(s) for s in shape.split("x")]
+        assert not (dims[0] == B and math.prod(dims[1:]) == k + 1), shape
+
+
+# ---------------------------------------------------------------------------
+# the best-first kernel (kernels/bestfirst): the XLA loop's answers
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("q", [math.inf, 2.0])
+@pytest.mark.parametrize("d,B,k", [(6, 8, 512), (5, 3, 10), (96, 1, 130)])
+def test_best_first_kernel_matches_xla_loop(q, d, B, k):
+    """Interpreted, the kernel returns the XLA loop's ids, distances,
+    comparisons and truncation flags, bit for bit, on a tie-heavy integer
+    corpus (exact sums): a budget that cuts the search, one that does not,
+    and none at all.  Widths of 5, 6 and 96 pad to segments of 8 and 128."""
+    from repro.kernels.bestfirst import best_first_pallas, best_first_ref, view
+
+    rng = np.random.default_rng(d * 100 + B)
+    X = rng.integers(0, 4, size=(900, d)).astype(np.float32)
+    Q = jnp.asarray(rng.integers(0, 4, size=(B, d)).astype(np.float32))
+    tree = vptree.build_vptree(X, metric="euclidean", seed=7)
+    arrays = (tree.vantage, tree.mu, tree.left, tree.right)
+    cap = 2 * tree.depth + 8
+    tv = view(arrays, jnp.asarray(X))
+    for budget in (0, 150, 900):
+        ref = best_first_ref(arrays, jnp.asarray(X), Q, jnp.int32(budget),
+                             q=q, k=k, stack_cap=cap)
+        got = best_first_pallas(tv, Q, jnp.int32(budget), q=q, k=k,
+                                stack_cap=cap, interpret=True)
+        for r, g in zip(ref, got):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_best_first_kernel_flags_a_full_stack_like_the_loop():
+    """A stack cap below the tree's depth drops pushes: the kernel flags
+    the same queries and returns the same partial search as the loop."""
+    from repro.kernels.bestfirst import best_first_pallas, best_first_ref, view
+
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(600, 4)).astype(np.float32)
+    Q = jnp.asarray(rng.normal(size=(4, 4)).astype(np.float32))
+    tree = vptree.build_vptree(X, metric="euclidean", seed=1)
+    arrays = (tree.vantage, tree.mu, tree.left, tree.right)
+    ref = best_first_ref(arrays, jnp.asarray(X), Q, jnp.int32(600), q=2.0,
+                         k=5, stack_cap=3)
+    got = best_first_pallas(view(arrays, jnp.asarray(X)), Q, jnp.int32(600),
+                            q=2.0, k=5, stack_cap=3, interpret=True)
+    assert np.asarray(ref[3]).any()
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(ref[3]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(ref[2]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+
+
+def test_best_first_kernel_applies_only_on_a_tpu_without_a_mask():
+    from repro.kernels import bestfirst
+
+    X = jnp.zeros((16, 32), jnp.float32)
+    on_tpu = jax.default_backend() == "tpu"
+    assert bestfirst.applies(X, "euclidean", None) == on_tpu
+    assert not bestfirst.applies(X, "manhattan", None)
+    assert not bestfirst.applies(X, "euclidean", jnp.ones(16, bool))
+    assert not bestfirst.applies(None, "euclidean", None)
+    assert not bestfirst.applies(jnp.zeros((16, 129)), "euclidean", None)
