@@ -27,20 +27,25 @@ from __future__ import annotations
 
 import numpy as np
 
-from chipbench.reference import dist64, exact_topk
+from chipbench.reference import dist64, exact_topk, row_shards
 
 #: served distances that descend by less than this share are ties
 ASCEND_RTOL = 1e-5
 
 
 def gather_rows(X, ids: np.ndarray, chunk: int = 8192) -> np.ndarray:
-    """Rows ``X[ids]`` of a device corpus, copied to the host in chunks."""
-    import jax.numpy as jnp
+    """Rows ``X[ids]`` of a device corpus (``ids`` in range), copied to the
+    host in chunks; of a row-sharded corpus, each row gathered from the
+    shard that holds it, on that shard's device."""
+    import jax
 
     flat = ids.reshape(-1)
     out = np.empty((flat.size, X.shape[1]), np.float32)
-    for s in range(0, flat.size, chunk):
-        out[s:s + chunk] = np.asarray(X[jnp.asarray(flat[s:s + chunk])])
+    for first, Xs in row_shards(X):
+        at = np.flatnonzero((flat >= first) & (flat < first + Xs.shape[0]))
+        for s in range(0, at.size, chunk):
+            part = jax.device_put(flat[at[s:s + chunk]] - first, Xs.sharding)
+            out[at[s:s + chunk]] = np.asarray(Xs[part])
     return out.reshape(ids.shape + (X.shape[1],))
 
 

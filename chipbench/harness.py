@@ -161,18 +161,36 @@ def drive(system, traffic: dict, pool: np.ndarray, *, seconds: float,
 
 def _traced(logdir: str, t_from: float, t_len: float):
     """A thread that traces ``t_len`` seconds from ``t_from`` seconds
-    after it starts; returns (thread, holder of the trace's path)."""
+    after it starts; returns (thread, holder of the trace's path, or of
+    the error that stopped the thread)."""
     holder: dict = {}
 
     def body():
-        time.sleep(t_from)
-        tr = tracing.Trace(logdir)
-        tr.start()
-        time.sleep(t_len)
-        holder["path"] = tr.stop()
+        try:
+            time.sleep(t_from)
+            tr = tracing.Trace(logdir)
+            tr.start()
+            time.sleep(t_len)
+            holder["path"] = tr.stop()
+        except Exception as e:  # handed to the run, which raises it
+            holder["error"] = e
 
     th = threading.Thread(target=body, name="chipbench-trace", daemon=True)
     return th, holder
+
+
+def wait_for_trace(thread: threading.Thread, holder: dict) -> str:
+    """Waits for the trace thread until it has written the trace (the
+    profiler's stop takes longer the more device events the window holds:
+    ~118 us each on a v5e host, and four chips write four planes); returns
+    the trace's path, or raises naming the seconds waited."""
+    t0 = time.monotonic()
+    thread.join()
+    if "path" not in holder:
+        raise RuntimeError(
+            f"no trace written after waiting {time.monotonic() - t0:.1f} s "
+            f"for the trace thread: {holder.get('error')!r}")
+    return holder["path"]
 
 
 def run_cell(root: str, workload: str, seed: int, seconds: float,
@@ -197,8 +215,12 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     k = int(config["k"])
     deadline_ms = float(config["deadline_ms"])
     data = config["data"]
+    n = int(data["n"])
+    shards = int(data.get("shards", 1))
+    mesh = (None if shards == 1
+            else datagen.corpus_mesh(n, shards, int(cell["chips"])))
 
-    X = datagen.make(data["generator"], seed, 0, int(data["n"]),
+    X = datagen.make(data["generator"], seed, 0, n, mesh=mesh,
                      **data.get("params", {}))
     pool = np.asarray(datagen.make(data["generator"], seed, 1,
                                    int(data["query_pool"]),
@@ -232,8 +254,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     rec = drive(system, traffic, pool, seconds=seconds, seed=seed,
                 deadline_ms=deadline_ms, k=k, on_start=on_start)
     counter.armed = False
+    trace_path = None
     if trace_thread is not None:
-        trace_thread.join(timeout=120)
+        trace_path = wait_for_trace(trace_thread, holder)
     counters = system.counters()
     system.stop()
     peak = memory_peak_bytes()
@@ -261,13 +284,14 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
-        reduced = tracing.reduce(tracing.read_events(holder["path"]))
+        reduced = tracing.reduce(tracing.read_events(trace_path))
         run = {
             "cell": cell, "config": config, "traffic": traffic,
             "counters": counters, "build_s": stages,
             "queue_ms": rec.queue_ms[ok], "e2e": e2e_values,
-            "trace": reduced, "device_kind": device["kind"],
-            "n": int(data["n"]), "d": int(X.shape[1]), "k": k,
+            "trace": reduced, "trace_path": trace_path,
+            "device_kind": device["kind"],
+            "n": n, "d": int(X.shape[1]), "k": k,
         }
         for m in spec["per_layer"]:
             v = load_reader(root, m["name"])(run)

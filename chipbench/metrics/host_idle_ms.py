@@ -7,7 +7,7 @@ from chipbench import spans
 
 
 def read(run):
-    red = spans.of_run(run, __file__)
+    red = spans.of_run(run)
     if red is None or not red["idle_s"]:
         return None
     return spans.per_batch_ms(run, spans.host_idle_s(red))

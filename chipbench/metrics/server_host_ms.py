@@ -6,7 +6,7 @@ from chipbench import spans
 
 
 def read(run):
-    red = spans.of_run(run, __file__)
+    red = spans.of_run(run)
     if red is None or not {"pad", "fetch"} & set(red["host_s"]):
         return None
     host = red["host_s"]

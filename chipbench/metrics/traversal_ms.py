@@ -7,7 +7,7 @@ from chipbench import spans
 
 
 def read(run):
-    red = spans.of_run(run, __file__)
+    red = spans.of_run(run)
     if red is None or "traversal" not in red["stage_s"]:
         return None
     return spans.per_batch_ms(run, red["stage_s"]["traversal"])

@@ -35,8 +35,6 @@ readers then return None.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 from typing import Iterable, Optional
 
 from chipbench import tracing
@@ -241,27 +239,16 @@ def reduce(ev: dict) -> Optional[dict]:
 _CACHE: dict = {}
 
 
-def of_run(run: dict, reader: str) -> Optional[dict]:
-    """``reduce`` of the trace the harness reduced into ``run["trace"]``:
-    the newest trace of the run's cell under ``chipbench/out/trace`` of
-    the checkout that holds the file ``reader``, taken only where its
-    window is the one ``run["trace"]`` measured; None where there is
+def of_run(run: dict) -> Optional[dict]:
+    """``reduce`` of the trace the harness wrote for the run
+    (``run["trace_path"]``), read once per trace; None where the run has
     none."""
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(reader))))
-    pattern = os.path.join(root, "chipbench", "out", "trace",
-                           f"{run['cell']['name']}-*", "plugins", "profile",
-                           "*", "*.xplane.pb")
-    paths = sorted(glob.glob(pattern), key=os.path.getmtime)
-    if not paths:
+    path = run.get("trace_path")
+    if path is None:
         return None
-    path = paths[-1]
     if path not in _CACHE:
         _CACHE[path] = reduce(read(path))
-    red = _CACHE[path]
-    if red is None or red["window_s"] != run["trace"]["window_s"]:
-        return None
-    return red
+    return _CACHE[path]
 
 
 def host_idle_s(red: dict) -> float:

@@ -54,7 +54,11 @@ def read(run):
 
 @pytest.fixture()
 def root(tmp_path):
-    """A benchmark directory with one tiny cell, added by files alone."""
+    """A benchmark directory with tiny cells, added by files alone."""
+    return make_root(tmp_path)
+
+
+def make_root(tmp_path):
     cb = tmp_path / "chipbench"
     for sub in ("configs", "traffic", "metrics"):
         (cb / sub).mkdir(parents=True)
@@ -258,3 +262,35 @@ def test_run_refuses_a_cpu_backend():
     assert p.returncode != 0
     assert "no TPU found" in p.stderr
     assert p.stdout.strip() == ""
+
+
+def test_the_run_waits_for_the_trace_however_long_its_stop_takes():
+    import threading
+
+    holder: dict = {}
+
+    def stop_slowly():
+        time.sleep(1.0)
+        holder["path"] = "trace.xplane.pb"
+
+    th = threading.Thread(target=stop_slowly)
+    th.start()
+    assert harness.wait_for_trace(th, holder) == "trace.xplane.pb"
+    assert not th.is_alive()
+
+
+def test_a_trace_that_is_not_written_raises_naming_the_seconds_waited(
+        tmp_path, monkeypatch):
+    class ProfilerBusy:
+        def __init__(self, logdir):
+            pass
+
+        def start(self):
+            raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(harness.tracing, "Trace", ProfilerBusy)
+    th, holder = harness._traced(str(tmp_path), 0.0, 0.0)
+    th.start()
+    with pytest.raises(RuntimeError, match=r"no trace written after waiting "
+                                           r"\d+\.\d s .*profiler busy"):
+        harness.wait_for_trace(th, holder)

@@ -13,7 +13,9 @@ operation's XLA module, or a ``chipbench.query`` span's batch size), and
   window, averaged over the devices that ran anything;
 * ``modules``: device seconds inside the window and executions that
   overlap it, per XLA module (line ``XLA Modules``), by the module's name
-  without its ``(id)`` suffix;
+  without its ``(id)`` suffix, summed over every device plane: a program
+  that runs on four chips counts four times its per-chip seconds;
+* ``devices``: the number of devices that ran an operation in the window;
 * ``batches``: the ``chipbench.query`` host spans (one per batch the server
   dispatched), each counted by the share of it that lies in the window, so
   that a batch across an edge counts as its device time does; and
@@ -125,7 +127,8 @@ def _union(intervals: Iterable[tuple]) -> list:
 
 
 def reduce(events: list, *, top: int = 10) -> dict:
-    """The numbers the metrics read from one trace (see the module doc)."""
+    """The numbers the metrics read from one trace (see the module doc):
+    ``busy_s`` is a mean over the devices, ``modules`` a sum over them."""
     spans = [(s, s + d, n, m) for p, l, n, s, d, m in events
              if not DEVICE_PLANE.match(p)]
     win = [(s, e) for s, e, n, _ in spans if n == WINDOW_SPAN]
